@@ -13,10 +13,9 @@ prompt/decode lengths) through
     prefill,
 
 and reports req/invoke (batch occupancy), tokens/s (simulated), decode-slot
-occupancy, $/1k tokens, and the KV memory footprint.  A sharded cell re-runs
-one workload on 1 device vs an 8-device (2 data x 4 model) host mesh via the
-``repro.launch.sharded_smoke`` subprocess and gates identical outputs plus
-the per-shard decode wire-bytes budget.  A speculation cell
+occupancy, $/1k tokens, and the KV memory footprint.  (The 1-device vs
+8-device sharded comparison is ``repro.launch.sharded_smoke``, run on its
+own with the device spoof set before JAX starts.)  A speculation cell
 re-runs one request soup with draft-and-verify speculative decoding off vs
 on (self-draft) and reports acceptance rate and target steps per emitted
 token at asserted-identical outputs.  A second cell drives
@@ -360,38 +359,6 @@ def _multiturn_cell(cfg, model, params, *, sharing, page_size=8, sys_len=16,
     }
 
 
-def _sharded_cell(arch):
-    """1-device vs 8-device (2x4 host mesh) sharded decode, same workload.
-
-    Runs ``repro.launch.sharded_smoke`` as a subprocess (the 8-device spoof
-    must be set before jax init, so it cannot run in this process): dense
-    token parity 1-dev == 8-dev, steady-state decode-step latency per mode,
-    and the per-shard decode wire-bytes budget (wire must not grow with the
-    pool — the shard_map lane merge ships softmax statistics, not pages).
-    """
-    import json
-    import os
-    import subprocess
-    import sys
-    import tempfile
-
-    fd, out = tempfile.mkstemp(suffix=".json")
-    os.close(fd)
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env.pop("XLA_FLAGS", None)      # the driver sets its own device spoof
-    try:
-        r = subprocess.run(
-            [sys.executable, "-m", "repro.launch.sharded_smoke",
-             "--arch", arch, "--out", out],
-            capture_output=True, text=True, env=env, timeout=1800)
-        assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-2000:])
-        with open(out) as f:
-            return json.load(f)
-    finally:
-        os.unlink(out)
-
-
 SPEC_K = 3              # draft tokens proposed per verify round
 SPEC_REQUESTS = 8
 SPEC_SESSIONS = 4
@@ -621,21 +588,6 @@ def run(n: int = 32, arch: str = "minicpm-2b", sessions: int = 8,
              "index_hits", "cow_splits", "kv_pages_high_water",
              "kv_high_water_kib", "park_storage_ops_usd"]))
 
-    sh = _sharded_cell(arch)
-    print(table(
-        "sharded decode: same workload, 1 device vs 8-device 2x4 host mesh "
-        "(slots on data, heads/lanes on model; fused paged backend under "
-        "shard_map) — identical outputs, step latency, per-shard wire bytes",
-        [{"mode": "1-device", **{k: sh["single"][k] for k in
-          ("steps", "decode_ms_p50", "wire_bytes_per_step")}},
-         {"mode": f"8-device {sh['sharded']['mesh']}",
-          **{k: sh["sharded"][k] for k in
-             ("steps", "decode_ms_p50", "wire_bytes_per_step")}}],
-        ["mode", "steps", "decode_ms_p50", "wire_bytes_per_step"]))
-    print(f"sharded outputs identical: {sh['identical_outputs']}; wire "
-          f"growth over 4x pool {sh['wire_growth_bytes']} B "
-          f"(budget {sh['wire_growth_budget_bytes']})")
-
     sp = [_speculation_cell(cfg, model, params, spec=s)
           for s in (False, True)]
     sp_off, sp_on = sp
@@ -723,12 +675,6 @@ def run(n: int = 32, arch: str = "minicpm-2b", sessions: int = 8,
         "spec_step_reduction": round(sp_off["steps"] / sp_on["steps"], 2),
         "spec_fewer_steps_than_baseline": sp_on["steps"] < sp_off["steps"],
         "spec_outputs_identical": True,        # asserted above
-        # multi-device sharded decode: the strict dense parity claim
-        # (1-device tokens == 8-device mesh tokens) plus the lane-sharded
-        # wire budget (decode wire bytes must not grow with the pool)
-        "sharded": sh,
-        "shardmap_identical_outputs": sh["identical_outputs"],
-        "shardmap_wire_within_budget": sh["wire_within_budget"],
         # elastic scale-to-zero fleet: pay-per-invocation + retention vs the
         # always-on VM — cheaper whenever traffic is bursty enough to idle,
         # at token-identical outputs (asserted above)
@@ -760,8 +706,6 @@ def run(n: int = 32, arch: str = "minicpm-2b", sessions: int = 8,
     assert summary["multiturn_prefill_halved"], (mt_off, mt_on)
     assert summary["spec_fewer_steps_than_baseline"], (sp_off, sp_on)
     assert summary["spec_steps_per_token"] <= 0.75, sp_on
-    assert summary["shardmap_identical_outputs"], sh
-    assert summary["shardmap_wire_within_budget"], sh
     assert summary["fleet_scaled_to_zero"], fc
     assert summary["fleet_cheaper_at_low_traffic"], fc
     save_artifact("BENCH_serving", summary)

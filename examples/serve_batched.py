@@ -17,7 +17,7 @@ from repro.launch.serve import run_serving
 
 
 def main() -> None:
-    frontend = run_serving("recurrentgemma-2b", n_requests=12, max_new=6,
+    frontend = run_serving("recurrentgemma-2b-smoke", n_requests=12, max_new=6,
                            sessions=3, batch_size=4)
     # per-session FIFO: completions must arrive in submission order
     for sess, ids in frontend.completions.items():

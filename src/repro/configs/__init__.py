@@ -1,9 +1,10 @@
 """Assigned architecture registry: ``--arch <id>`` resolves here.
 
 Each module defines ``CONFIG`` (the exact assigned full-scale config) and the
-registry exposes ``get(name)`` / ``list_archs()`` plus ``input_specs`` for the
-dry-run (ShapeDtypeStruct stand-ins — no allocation ever happens for the full
-configs; they are exercised only via ``launch/dryrun.py``).
+registry exposes ``get(name)`` / ``list_archs()``.  ``get`` also resolves
+``<arch>-smoke``, the name :meth:`ArchConfig.reduced` gives the tiny
+same-family config that CPU tests and examples serve; the plain name is
+always the published widths.
 """
 
 from __future__ import annotations
@@ -40,16 +41,28 @@ _ALIAS = {
 }
 
 
+SMOKE_SUFFIX = "-smoke"
+
+
 def get(name: str) -> ArchConfig:
+    if name.endswith(SMOKE_SUFFIX):
+        return get(name[:-len(SMOKE_SUFFIX)]).reduced()
     mod_name = _ALIAS.get(name, name.replace("-", "_").replace(".", "p"))
     if mod_name not in _ARCHS:
-        raise KeyError(f"unknown arch {name!r}; available: {list(_ALIAS)}")
+        raise KeyError(f"unknown arch {name!r}; available: {names()}")
     mod = importlib.import_module(f".{mod_name}", __package__)
     return mod.CONFIG
 
 
 def list_archs() -> List[str]:
+    """The published configs."""
     return list(_ALIAS.keys())
+
+
+def names() -> List[str]:
+    """Every name :func:`get` resolves: each published config and its
+    ``-smoke`` reduction."""
+    return [a + s for a in list_archs() for s in ("", SMOKE_SUFFIX)]
 
 
 def all_configs() -> Dict[str, ArchConfig]:

@@ -2,14 +2,9 @@
 
 ``repro.dist.sharding`` is the only module that names mesh axes; everything
 else (models, train/serve steps, the dry-run driver) talks to it through
-named rules.  Importing the package installs the small jax version shims in
-:mod:`repro.dist.compat` (no-ops on modern jax).
+named rules.
 """
 
-from . import compat as _compat
-
-_compat.install()
-
-from . import sharding  # noqa: E402  (compat must install first)
+from . import sharding
 
 __all__ = ["sharding"]

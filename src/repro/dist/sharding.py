@@ -281,7 +281,7 @@ def cache_shardings(cache_abs: PyTree, mesh) -> PyTree:
     (..., n_pages, page_size, H, D) have no slot axis — every slot's page
     table indexes one shared pool, so the pool stays *replicated over dp*
     and shards its within-page lane dim on model (heads, then pages, as
-    fallbacks — the paged-attention kernel slices per-(page, head) blocks
+    fallbacks — the paged-attention kernel slices one page per grid step
     by table index, which head- or page-sharded pools can only serve by
     all-gathering the pool);
     page tables (..., n_slots, max_pages) follow the slot batch onto dp.
@@ -312,8 +312,8 @@ def cache_shardings(cache_abs: PyTree, mesh) -> PyTree:
 
         if key in _CACHE_POOL_KEYS and nd >= 4:  # (..., Np, ps, H, D) shared pool
             # within-page lane dim first, then heads, then pages.  The paged
-            # decode kernel streams the pool one (page, head) block per grid
-            # step, so a pool sharded across heads or pages turns every
+            # decode kernel streams the pool one page block per grid step,
+            # so a pool sharded across heads or pages turns every
             # block slice into a cross-shard read XLA answers by
             # all-gathering the whole pool each step (measured on the 16x16
             # decode_32k cells: 73 GB/device wire page-sharded, 65 GB

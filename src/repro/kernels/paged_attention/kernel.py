@@ -11,12 +11,16 @@ physical page ``page_table[b, j]`` before the grid step runs — the DMA
 engine fetches pages by table lookup and the gathered cache never exists in
 HBM.
 
-Tiling: grid ``(B, Hkv, max_pages)`` with the kv-page index innermost
-(sequential on TPU), one page per kv block.  The online-softmax running
-max / normalizer / accumulator live in VMEM scratch across the page sweep,
-exactly as in the flash kernel; the S=1 query block is the ``(G, D)`` head
-group of one kv head, so GQA costs one grid axis instead of a materialized
-``jnp.repeat``.
+Tiling: grid ``(B, max_pages)`` with the kv-page index innermost
+(sequential on TPU), one page **of all kv heads** per kv block: the K/V
+block is ``(1, page_size, Hkv, D)`` and the S=1 query block is the whole
+``(Hkv, G, D)`` head set of one slot.  The last two dims of every block are
+then full array dims, which is what Mosaic requires of a block whose dims
+are not multiples of the (8, 128) tile — a ``(1, page_size, 1, D)`` block
+over a 36-head pool is refused.  The online-softmax running max /
+normalizer / accumulator live in VMEM scratch across the page sweep,
+exactly as in the flash kernel, and GQA is a contraction batched over the
+kv heads instead of a materialized ``jnp.repeat``.
 
 Masking: lane ``t`` of page ``j`` is attendable iff its page is mapped
 (``page_table[b, j] >= 0``), ``t < lengths[b]`` (the slot's live length
@@ -56,7 +60,7 @@ def _paged_attn_kernel(len_ref, qpos_ref, pt_ref, base_ref, q_ref, k_ref,
                        pos_stride: int, n_blocks: int, scale: float,
                        window: Optional[int]):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -64,13 +68,13 @@ def _paged_attn_kernel(len_ref, qpos_ref, pt_ref, base_ref, q_ref, k_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale              # (G, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)                # (ps, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)                # (ps, D)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, ps)
+    q = q_ref[0].astype(jnp.float32) * scale                 # (Hkv, G, D)
+    k = k_ref[0].astype(jnp.float32)                         # (ps, Hkv, D)
+    v = v_ref[0].astype(jnp.float32)                         # (ps, Hkv, D)
+    s = jnp.einsum("hgd,thd->hgt", q, k)                     # (Hkv, G, ps)
 
     t_pos = (j * pos_stride + base_ref[0]
-             + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+             + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2))
     mask = (t_pos < len_ref[b]) & (pt_ref[b, j] >= 0)
     if window is not None:
         mask &= t_pos > qpos_ref[b] - window
@@ -83,17 +87,17 @@ def _paged_attn_kernel(len_ref, qpos_ref, pt_ref, base_ref, q_ref, k_ref,
     # running max is still NEG_INF and exp(s - m) == 1 there, which would
     # leak phantom weight into l/acc (scrambled tables hit this; the causal
     # flash sweep never does)
-    p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - m_new[..., None]), 0.0)
     l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())))
+    acc_ref[...] = acc_ref[...] * alpha[..., None] + jnp.einsum(
+        "hgt,thd->hgd", p, v)
     m_ref[...] = m_new
 
     @pl.when(j == n_blocks - 1)
     def _finish():
-        acc_out[0, 0] = acc_ref[...]
-        m_out[0, 0] = m_ref[...]
-        l_out[0, 0] = l_ref[...]
+        acc_out[0] = acc_ref[...]
+        m_out[0] = m_ref[...]
+        l_out[0] = l_ref[...]
 
 
 def paged_attention_kernel(q: jnp.ndarray, kp: jnp.ndarray, vp: jnp.ndarray,
@@ -125,7 +129,7 @@ def paged_attention_kernel(q: jnp.ndarray, kp: jnp.ndarray, vp: jnp.ndarray,
     B, Hkv, G, D = q.shape
     page_size = kp.shape[1]
     max_pages = page_table.shape[1]
-    grid = (B, Hkv, max_pages)
+    grid = (B, max_pages)
     if pos_stride is None:
         pos_stride = page_size
     if lane_base is None:
@@ -135,34 +139,34 @@ def paged_attention_kernel(q: jnp.ndarray, kp: jnp.ndarray, vp: jnp.ndarray,
         _paged_attn_kernel, pos_stride=pos_stride, n_blocks=max_pages,
         scale=1.0 / math.sqrt(D), window=window)
 
-    def q_map(b, h, j, lens, qp, pt, base):
-        return (b, h, 0, 0)
+    def q_map(b, j, lens, qp, pt, base):
+        return (b, 0, 0, 0)
 
-    def kv_map(b, h, j, lens, qp, pt, base):
+    def kv_map(b, j, lens, qp, pt, base):
         # unmapped blocks clamp to page 0: a benign (masked) fetch, and on
         # TPU a revisited block index skips the DMA entirely
-        return (jnp.maximum(pt[b, j], 0), 0, h, 0)
+        return (jnp.maximum(pt[b, j], 0), 0, 0, 0)
 
-    def o_map(b, h, j, lens, qp, pt, base):
-        return (b, h, 0)
+    def o_map(b, j, lens, qp, pt, base):
+        return (b, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), q_map),
-            pl.BlockSpec((1, page_size, 1, D), kv_map),
-            pl.BlockSpec((1, page_size, 1, D), kv_map),
+            pl.BlockSpec((1, Hkv, G, D), q_map),
+            pl.BlockSpec((1, page_size, Hkv, D), kv_map),
+            pl.BlockSpec((1, page_size, Hkv, D), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, G, D), q_map),
-            pl.BlockSpec((1, 1, G), o_map),
-            pl.BlockSpec((1, 1, G), o_map),
+            pl.BlockSpec((1, Hkv, G, D), q_map),
+            pl.BlockSpec((1, Hkv, G), o_map),
+            pl.BlockSpec((1, Hkv, G), o_map),
         ],
         scratch_shapes=[
-            pltpu.VMEM((G, D), jnp.float32),   # acc
-            pltpu.VMEM((G,), jnp.float32),     # running max
-            pltpu.VMEM((G,), jnp.float32),     # running normalizer
+            pltpu.VMEM((Hkv, G, D), jnp.float32),   # acc
+            pltpu.VMEM((Hkv, G), jnp.float32),      # running max
+            pltpu.VMEM((Hkv, G), jnp.float32),      # running normalizer
         ],
     )
     return pl.pallas_call(

@@ -7,9 +7,10 @@ between decode steps) or by whole-batch generation (enc-dec families) — the
 serverless request path with a real model behind it.  ``mode='per-session'``
 runs the old one-queue-per-session batcher as the cost baseline.
 
-Usage:
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-14b --requests 12 \
-      --sessions 3 --batch-size 4 --prompt-len 16
+Usage (``--arch minicpm-2b`` serves the published widths, ``minicpm-2b-smoke``
+the tiny reduction that runs on a CPU):
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-14b-smoke \
+      --requests 12 --sessions 3 --batch-size 4 --prompt-len 16
 """
 
 from __future__ import annotations
@@ -27,9 +28,11 @@ from ..coord.serving_front import InferenceRequest, ServingFrontend
 from ..core import SimCloud
 from ..core.storage import PageBlobStore
 from ..models import build_model
-from ..serve.engine import make_decode_step, make_prefill
+from ..serve.engine import init_serving_params, make_decode_step, make_prefill
 from ..serve.fleet import FleetController
 from ..serve.scheduler import DecodeScheduler, supports_continuous
+from .compile_cache import enable_compile_cache
+from .mesh import make_mesh
 
 
 def _whole_batch_model_fn(model, params, max_new: int):
@@ -248,7 +251,7 @@ def _parse_mesh(spec: Optional[str]):
             f"--mesh {spec} needs {dp * mp} devices, have "
             f"{jax.device_count()} (CPU: set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={dp * mp})")
-    return jax.make_mesh((dp, mp), ("data", "model"))
+    return make_mesh((dp, mp), ("data", "model"))
 
 
 def run_serving(arch: str, n_requests: int = 12, *, max_new: int = 8,
@@ -266,17 +269,20 @@ def run_serving(arch: str, n_requests: int = 12, *, max_new: int = 8,
                 fleet: int = 0, min_workers: int = 0,
                 max_workers: Optional[int] = None,
                 scale_to_zero: bool = False):
-    cfg = configs.get(arch).reduced()
+    """Serve ``arch`` (a published config, or its ``<arch>-smoke``
+    reduction) with random bf16 serving weights."""
+    cfg = configs.get(arch)
     model = build_model(cfg)
-    params = model.init(jax.random.key(0))
+    params = init_serving_params(model, jax.random.key(0))
 
     draft_model = draft_params = None
     if spec_draft is not None:
         if spec_draft == arch:              # self-draft: reuse the weights
             draft_model, draft_params = model, params
         else:
-            draft_model = build_model(configs.get(spec_draft).reduced())
-            draft_params = draft_model.init(jax.random.key(0))
+            draft_model = build_model(configs.get(spec_draft))
+            draft_params = init_serving_params(draft_model,
+                                               jax.random.key(0))
         spec_k = spec_k or 3
 
     cloud = SimCloud(seed=seed)
@@ -366,7 +372,9 @@ def run_serving(arch: str, n_requests: int = 12, *, max_new: int = 8,
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", required=True, choices=configs.list_archs())
+    ap.add_argument("--arch", required=True, choices=configs.names(),
+                    help="a published config, or <arch>-smoke for its tiny "
+                         "same-family reduction")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--sessions", type=int, default=3)
@@ -411,7 +419,7 @@ def main() -> None:
                     help="decode attention over the paged pool: materialize "
                          "the gathered view in HBM (reference) or stream "
                          "pages through the Pallas table-indirect kernel")
-    ap.add_argument("--spec-draft", default=None, choices=configs.list_archs(),
+    ap.add_argument("--spec-draft", default=None, choices=configs.names(),
                     help="draft arch for draft-and-verify speculative "
                          "decoding (same arch = self-draft; greedy + paged + "
                          "gather backend only; output stays token-identical "
@@ -439,6 +447,7 @@ def main() -> None:
                          "devices with XLA_FLAGS="
                          "--xla_force_host_platform_device_count=8)")
     args = ap.parse_args()
+    enable_compile_cache()
     run_serving(args.arch, args.requests, max_new=args.max_new,
                 sessions=args.sessions, batch_size=args.batch_size,
                 prompt_len=args.prompt_len, mode=args.mode,

@@ -1,16 +1,8 @@
-import os
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=8"
-                               ).strip()
-
 """Sharded-decode smoke: the same serving workload on one device and on an
 8-device (2 data x 4 model) host mesh, asserted token-identical.
 
-The two lines above MUST stay first (before any jax import): jax locks the
-device count at first init.  Run this as its own process — never import it
-from a process that wants the real device count.
+It needs 8 devices.  On a CPU host the caller spoofs them, before JAX
+starts: ``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 
 For each mode the driver runs a small multi-session workload through
 :class:`repro.serve.scheduler.DecodeScheduler` (chunked admission, paged
@@ -27,13 +19,14 @@ the shard_map lane/head decomposition), then measures
     absolute number, so the growth — not the total — is what catches a
     full-pool all-gather regressing in.
 
-The default arch is dense (``minicpm-2b``): dense holds the *strict*
+The default arch is dense (``minicpm-2b-smoke``): dense holds the *strict*
 1-device == 8-device token-parity claim (cross-shard bf16 reduction drift
 stays inside its argmax margins; see tests/test_sched_differential.py's
 sharded section for why moe/hybrid compare mesh-vs-mesh instead).
 
 Usage:
-  PYTHONPATH=src python -m repro.launch.sharded_smoke --out smoke.json
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+      PYTHONPATH=src python -m repro.launch.sharded_smoke --out smoke.json
 """
 
 import argparse
@@ -48,6 +41,7 @@ from .. import configs
 from ..models import build_model
 from ..serve.scheduler import DecodeScheduler
 from . import hlo_analysis
+from .mesh import make_mesh
 
 PAGE_SIZE = 4            # divides the mesh's model axis -> lane decomposition
 N_SLOTS = 4              # divides the mesh's data axis
@@ -106,9 +100,13 @@ def _decode_step_stats(sched, *, reps=20):
     }
 
 
-def run_smoke(arch="minicpm-2b", *, attn_backend="paged_kernel",
+def run_smoke(arch="minicpm-2b-smoke", *, attn_backend="paged_kernel",
               n_requests=6, sessions=3, prompt_len=12, max_new=6):
-    cfg = configs.get(arch).reduced()
+    if jax.device_count() < 8:
+        raise SystemExit(
+            f"sharded smoke needs 8 devices, have {jax.device_count()} (CPU: "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count=8)")
+    cfg = configs.get(arch)
     model = build_model(cfg)
     params = model.init(jax.random.key(0))
     max_seq = prompt_len + max_new
@@ -117,7 +115,7 @@ def run_smoke(arch="minicpm-2b", *, attn_backend="paged_kernel",
               "requests": n_requests, "sessions": sessions,
               "prompt_len": prompt_len, "max_new": max_new}
     modes = {"single": None,
-             "sharded": jax.make_mesh((2, 4), ("data", "model"))}
+             "sharded": make_mesh((2, 4), ("data", "model"))}
     outputs = {}
     for name, mesh in modes.items():
         sched = DecodeScheduler(model, params, n_slots=N_SLOTS,
@@ -159,8 +157,8 @@ def run_smoke(arch="minicpm-2b", *, attn_backend="paged_kernel",
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="minicpm-2b",
-                    choices=configs.list_archs())
+    ap.add_argument("--arch", default="minicpm-2b-smoke",
+                    choices=configs.names())
     ap.add_argument("--attn-backend", default="paged_kernel",
                     choices=["gather", "paged_kernel"])
     ap.add_argument("--requests", type=int, default=6)

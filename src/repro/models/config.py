@@ -205,7 +205,7 @@ class ArchConfig:
     def reduced(self) -> "ArchConfig":
         """Tiny same-family config for CPU smoke tests."""
         kw: Dict = dict(
-            name=self.name + "-smoke",
+            name=self.name + "-smoke",      # configs.get resolves this name
             n_layers=min(self.n_layers, 4) if self.family != "hybrid" else 3,
             d_model=64,
             n_heads=4,

@@ -6,7 +6,8 @@ dim and differentiable; models compose these into scanned layer stacks.
 
 Compute dtype discipline: parameters are stored in ``param_dtype`` (fp32
 masters) and cast to ``compute_dtype`` (bf16) at use — the usual mixed
-precision recipe, and what the roofline's bf16 peak assumes.
+precision recipe, and what the roofline's bf16 peak assumes.  Training keeps
+the masters; serving casts once at set-up (:func:`to_compute_dtype`).
 """
 
 from __future__ import annotations
@@ -21,6 +22,31 @@ PyTree = Any
 
 COMPUTE_DTYPE = jnp.bfloat16
 PARAM_DTYPE = jnp.float32
+
+
+# Keys of the parameter leaves (or subtrees) whose uses compute in float32:
+# per-head qk norms applied to fp32 statistics, the MoE router, the SSM
+# decay/skip/gated-norm leaves and the RG-LRU gates.  Every other floating
+# leaf is only ever read through a cast to COMPUTE_DTYPE.
+F32_PARAM_KEYS = frozenset({
+    "q_norm", "k_norm", "router", "dt_bias", "A_log", "D", "norm",
+    "gate_w_a", "gate_b_a", "gate_w_x", "gate_b_x", "a_param",
+})
+
+
+def to_compute_dtype(params: PyTree) -> PyTree:
+    """Serving weights: every leaf that each use casts to COMPUTE_DTYPE is
+    cast once, here, so the values every matmul sees are unchanged and the
+    weights take half the memory.  Leaves under :data:`F32_PARAM_KEYS`
+    keep their dtype."""
+    def leaf(path, x):
+        if (not jnp.issubdtype(x.dtype, jnp.floating)
+                or any(getattr(k, "key", None) in F32_PARAM_KEYS
+                       for k in path)):
+            return x
+        return x.astype(COMPUTE_DTYPE)
+
+    return jax.tree_util.tree_map_with_path(leaf, params)
 
 
 def cast(x: jnp.ndarray) -> jnp.ndarray:

@@ -45,6 +45,19 @@ def _constrain_cache(cache, cache_specs):
     return shd.constrain_tree(cache, cache_specs)
 
 
+def init_serving_params(model, key):
+    """Random serving weights from ``key``, in the dtypes serving reads
+    them in (:func:`repro.models.layers.to_compute_dtype`).
+
+    Initialisation and cast are one jitted program, so the float32 masters
+    are never whole in device memory: at published widths they would not
+    fit next to the bf16 copy.  The values are those of ``model.init(key)``
+    cast, so serving outputs do not change."""
+    from ..models.layers import to_compute_dtype
+
+    return jax.jit(lambda k: to_compute_dtype(model.init(k)))(key)
+
+
 def make_decode_step(model, *, policy=None, cache_specs=None) -> Callable:
     def serve_step(params, cache, tokens):
         with _policy_scope(policy):

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import repro.dist  # noqa: F401  (installs the AbstractMesh compat shim)
 from repro import configs
 from repro.models import build_model, kvcache
 from repro.serve.scheduler import DecodeScheduler

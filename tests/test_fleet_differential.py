@@ -34,7 +34,6 @@ import jax
 import numpy as np
 import pytest
 
-import repro.dist  # noqa: F401  (installs the AbstractMesh compat shim)
 from repro import configs
 from repro.coord import MembershipService
 from repro.core import FaultPlan

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import repro.dist  # noqa: F401  (installs the AbstractMesh compat shim)
 from repro import configs
 from repro.core.storage import PageBlobStore
 from repro.models import build_model, kvcache
@@ -441,5 +440,5 @@ def test_pool_sizing_validated_at_startup():
 
     from repro.launch.serve import run_serving
     with pytest.raises(ValueError, match="max-size admission"):
-        run_serving("minicpm-2b", n_requests=1, max_new=8, prompt_len=16,
+        run_serving("minicpm-2b-smoke", n_requests=1, max_new=8, prompt_len=16,
                     batch_size=4, page_size=4, kv_pages=8, quiet=True)

@@ -14,6 +14,7 @@ import pytest
 
 from repro import configs
 from repro.models import build_model
+from repro.models.layers import F32_PARAM_KEYS, to_compute_dtype
 from repro.train import AdamWConfig, make_train_step
 from repro.train.step import TrainStepConfig, init_train_state
 
@@ -73,6 +74,44 @@ def test_arch_decode_step(arch):
     assert logits.shape == (B, 1, cfg.padded_vocab)
     assert bool(jnp.isfinite(logits.astype(jnp.float32)).all())
     assert int(cache2["length"]) == 1
+
+
+def test_smoke_names_resolve_to_reduced_configs():
+    """``<arch>-smoke`` is the name ``reduced()`` gives; the registry
+    resolves it, and the plain name stays the published config."""
+    for arch in ARCHS:
+        small = configs.get(arch + "-smoke")
+        assert small == configs.get(arch).reduced()
+        assert configs.get(small.name) == small
+        assert configs.get(arch).name == arch
+    assert set(configs.names()) == {a + s for a in ARCHS
+                                    for s in ("", configs.SMOKE_SUFFIX)}
+    with pytest.raises(KeyError):
+        configs.get("no-such-arch-smoke")
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+def test_serving_weights_keep_logits_bitwise(arch):
+    """Serving casts once, at set-up, what every use casts anyway: the bf16
+    serving weights give bitwise the logits of the float32 masters, in the
+    forward pass and in a decode step."""
+    cfg = configs.get(arch + "-smoke")
+    model = build_model(cfg)
+    p32 = model.init(jax.random.key(0))
+    p16 = to_compute_dtype(p32)
+    for path, leaf in jax.tree_util.tree_leaves_with_path(p16):
+        keep = any(getattr(k, "key", None) in F32_PARAM_KEYS for k in path)
+        want = jnp.float32 if keep else jnp.bfloat16
+        assert leaf.dtype == want, (arch, jax.tree_util.keystr(path))
+    batch = _smoke_batch(cfg)
+    fwd = jax.jit(model.apply)
+    assert (np.asarray(fwd(p32, batch)).tobytes()
+            == np.asarray(fwd(p16, batch)).tobytes()), arch
+    cache = model.init_cache(2, 12)
+    tok = batch["tokens"][:, :1]
+    step = jax.jit(model.decode_step)
+    assert (np.asarray(step(p32, cache, tok)[0]).tobytes()
+            == np.asarray(step(p16, cache, tok)[0]).tobytes()), arch
 
 
 @pytest.mark.parametrize("arch", ["qwen3-14b", "starcoder2-3b", "mamba2-1.3b",

@@ -11,7 +11,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import repro.dist  # noqa: F401  (installs the AbstractMesh compat shim)
 from repro.kernels.paged_attention import reference_paged_attention
 from repro.models import kvcache
 from repro.serve.engine import generate

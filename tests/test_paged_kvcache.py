@@ -10,7 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import repro.dist  # noqa: F401  (installs the AbstractMesh compat shim)
 from repro import configs
 from repro.models import build_model, kvcache
 from repro.serve.engine import generate
@@ -349,8 +348,9 @@ def test_paged_cache_shardings_resolve_on_16x16():
     # kernel's table-indirect page slices would all-gather the pool)
     assert all(e is None or e == "model" for e in specs["kp"])
     assert specs["kp"][2] == "model" and specs["kp"][1] is None
-    # page table (L, n_slots, max_pages): slot batch on data
-    assert specs["page_table"][1] == ("data",)
+    # page table (L, n_slots, max_pages): slot batch on data (PartitionSpec
+    # normalizes a one-axis tuple entry to the bare axis name)
+    assert specs["page_table"][1] == "data"
 
 
 def test_paged_scheduler_decodes_under_concrete_mesh():

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import repro.dist  # noqa: F401  (installs the AbstractMesh compat shim)
 from repro import configs
 from repro.models import build_model, kvcache
 from repro.serve.engine import make_decode_step, make_prefill
@@ -433,7 +432,9 @@ needs_mesh = pytest.mark.skipif(
 
 
 def _host_mesh():
-    return jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    return make_mesh((2, 4), ("data", "model"))
 
 
 @needs_mesh

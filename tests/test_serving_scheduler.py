@@ -10,7 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import repro.dist  # noqa: F401  (installs the AbstractMesh compat shim)
 from repro import configs
 from repro.core import SimCloud
 from repro.core.simcloud import FaultPlan
@@ -100,7 +99,7 @@ def test_sampling_flags_rejected_on_greedy_fallback():
     """The whole-batch fallback decodes greedily — sampling knobs must fail
     loudly instead of being silently dropped."""
     with pytest.raises(ValueError, match="continuous scheduler"):
-        run_serving("whisper-base", n_requests=2, max_new=3, sessions=1,
+        run_serving("whisper-base-smoke", n_requests=2, max_new=3, sessions=1,
                     temperature=0.8, quiet=True)
 
 
@@ -174,7 +173,7 @@ def test_crash_redelivers_batch_without_duplicating_completions():
 def test_whole_batch_fallback_for_encdec():
     """Families without a per-slot decode path (enc-dec) fall back to the
     shared whole-batch flavour and still cross-session batch."""
-    fe = run_serving("whisper-base", n_requests=6, max_new=3, sessions=2,
+    fe = run_serving("whisper-base-smoke", n_requests=6, max_new=3, sessions=2,
                      batch_size=3, quiet=True)
     assert fe.scheduler is None and fe.mode == "shared"
     assert sum(len(v) for v in fe.completions.values()) == 6
@@ -196,9 +195,10 @@ def test_cache_shardings_resolve_on_16x16():
     specs = sched.cache_specs
     # kv rings (L, B, T, H, D): batch on data; the reduced config's 4 kv
     # heads don't divide model=16, so the guard falls back to the time dim
-    assert specs["k"][1] == ("data",)
+    # (PartitionSpec normalizes a one-axis tuple entry to the bare name)
+    assert specs["k"][1] == "data"
     assert specs["k"][2] == "model"
-    assert specs["positions"][1] == ("data",)
+    assert specs["positions"][1] == "data"
 
 
 def test_scheduler_decodes_under_concrete_mesh():
@@ -250,3 +250,24 @@ def test_topk_ge_vocab_and_topk_one():
     tok = sampling.temperature_sample(jax.random.key(0), logits,
                                       temperature=1e-4, top_k=0)
     assert int(tok[0]) == 1
+
+
+def test_run_serving_bf16_weights_serve_the_fp32_tokens():
+    """``run_serving`` serves bf16 weights made by one jitted init+cast
+    program; the tokens are those the float32 masters serve."""
+    from repro.launch.serve import spawn_workload
+
+    kw = dict(n_requests=6, sessions=2, prompt_len=16, max_new=4)
+    fe = run_serving("minicpm-2b-smoke", batch_size=3, quiet=True, **kw)
+    cfg = configs.get("minicpm-2b-smoke")
+    model = build_model(cfg)
+    cloud = SimCloud(seed=0)
+    ref = build_frontend(cloud, cfg, model, model.init(jax.random.key(0)),
+                         mode="continuous", batch_size=3,
+                         max_new=kw["max_new"], prompt_len=kw["prompt_len"])
+    spawn_workload(cloud, ref, vocab=cfg.vocab, **kw)
+    cloud.run()
+    assert fe.completions == ref.completions
+    for sess, outs in ref.results.items():
+        assert [np.asarray(t).tolist() for t in fe.results[sess]] == \
+            [np.asarray(t).tolist() for t in outs]

@@ -108,3 +108,15 @@ def test_attn_mode_specs():
     assert head.specs["q_heads"][2] == "model"
     assert seq.specs["q_heads"][1] == "model"
     assert seq.specs["kv_heads"] == P(("data",), None, None, None)
+
+
+def test_repo_meshes_have_auto_axes():
+    """The sharding layer steers GSPMD with sharding constraints, which
+    ``Explicit`` mesh axes (``jax.make_mesh``'s default) refuse."""
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1, 1), ("data", "model"))
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+    assert mesh.axis_names == ("data", "model")

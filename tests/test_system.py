@@ -100,7 +100,7 @@ def test_wire_bytes_regression_gate():
 def test_serving_driver():
     from repro.launch.serve import run_serving
 
-    fe = run_serving("whisper-base", n_requests=6, max_new=3, sessions=2,
+    fe = run_serving("whisper-base-smoke", n_requests=6, max_new=3, sessions=2,
                      batch_size=3)
     assert sum(len(v) for v in fe.completions.values()) == 6
 
@@ -115,3 +115,24 @@ def test_elastic_scaling_example():
     r = _run([sys.executable, "examples/elastic_scaling.py"])
     assert r.returncode == 0, r.stderr[-1500:]
     assert "single system image holds" in r.stdout
+
+
+def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
+    """With ``JAX_COMPILATION_CACHE_DIR`` set the entry points set no cache
+    dir of their own; without it the cache is the repo's fixed
+    ``.jax_cache/``."""
+    import jax
+
+    from repro.launch import compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv(compile_cache.ENV, str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv(compile_cache.ENV)
+        assert compile_cache.enable_compile_cache() == str(ROOT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(ROOT / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
