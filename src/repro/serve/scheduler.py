@@ -131,6 +131,7 @@ from ..models import kvcache
 from . import sampling
 from .engine import _policy_scope, make_chunk_step, make_offload_steps
 from .lifecycle import Slot, SlotState
+from .tracing import span
 
 CONTINUOUS_FAMILIES = ("dense", "moe", "ssm", "hybrid")
 
@@ -558,34 +559,35 @@ class DecodeScheduler:
         bounded by its ``max_pages * page_size`` span instead.  SSM states
         never bound the budget beyond the output ring.
         """
-        prompt = np.asarray(prompt)
-        P = int(prompt.shape[-1])
-        limit = self.max_seq
-        cache_len = getattr(self.model, "cache_len", None)
-        has_full_ring = (self._has_kv
-                         and cache_len is not None
-                         and cache_len(self.max_seq + 1) > self.max_seq)
-        if has_full_ring:
-            room = self.max_seq - P
-            if room <= 0:
-                raise ValueError(
-                    f"request {request_id!r}: prompt of {P} "
-                    f"tokens leaves no decode room in the max_seq={self.max_seq} "
-                    "full-attention ring; size max_seq >= prompt + max_new")
-            limit = min(limit, room)
-        elif self.kv_mode == "paged" and self._has_kv:
-            # windowed attention wraps a ring but cannot wrap the linear
-            # page table: bound the budget by the table's span
-            room = self.max_pages * self.page_size - P
-            if room <= 0:
-                raise ValueError(
-                    f"request {request_id!r}: prompt of {P} tokens overruns "
-                    f"the {self.max_pages}x{self.page_size} page table")
-            limit = min(limit, room)
-        max_new = max(1, min(max_new, limit))
-        self.pending.append(_Request(session, request_id, prompt, max_new,
-                                     submit_step=self.steps))
-        self._fill_slots()
+        with span("submit", request_id=request_id):
+            prompt = np.asarray(prompt)
+            P = int(prompt.shape[-1])
+            limit = self.max_seq
+            cache_len = getattr(self.model, "cache_len", None)
+            has_full_ring = (self._has_kv
+                             and cache_len is not None
+                             and cache_len(self.max_seq + 1) > self.max_seq)
+            if has_full_ring:
+                room = self.max_seq - P
+                if room <= 0:
+                    raise ValueError(
+                        f"request {request_id!r}: prompt of {P} "
+                        f"tokens leaves no decode room in the max_seq={self.max_seq} "
+                        "full-attention ring; size max_seq >= prompt + max_new")
+                limit = min(limit, room)
+            elif self.kv_mode == "paged" and self._has_kv:
+                # windowed attention wraps a ring but cannot wrap the linear
+                # page table: bound the budget by the table's span
+                room = self.max_pages * self.page_size - P
+                if room <= 0:
+                    raise ValueError(
+                        f"request {request_id!r}: prompt of {P} tokens overruns "
+                        f"the {self.max_pages}x{self.page_size} page table")
+                limit = min(limit, room)
+            max_new = max(1, min(max_new, limit))
+            self.pending.append(_Request(session, request_id, prompt, max_new,
+                                         submit_step=self.steps))
+            self._fill_slots()
 
     def busy(self) -> bool:
         """In-flight work pending.  PARKED retention is not work: a parked
@@ -757,25 +759,26 @@ class DecodeScheduler:
 
     def _admit(self, slot: Slot, req: _Request,
                plan: Optional[_MatchPlan] = None) -> None:
-        if self.kv_mode == "paged":
-            self._admit_paged(slot, req, plan or _MatchPlan())
-            return
-        prompt = jnp.asarray(req.prompt, jnp.int32)[None]      # (1, P)
-        logits, one = self._prefill(self.params, prompt)
-        tok = self._sample(logits[:, -1])                      # (1,)
-        self.cache = kvcache.cache_insert_slot(self.cache, one, slot.index)
-        self.last_tokens = self.last_tokens.at[slot.index].set(tok[0])
-        self.out_buf = self.out_buf.at[slot.index, 0].set(tok[0])
-        self.out_pos = self.out_pos.at[slot.index].set(1)
-        slot.to(SlotState.ADMITTING).to(SlotState.ACTIVE)  # monolithic prefill
-        slot.req = req
-        slot.n_out = 1
-        slot.admitted_step = self.steps
-        slot.submitted_step = req.submit_step
-        slot.active_since = self.steps
-        self._active_sessions.add(req.session)
-        self.prefill_tokens += int(prompt.shape[1])
-        self.admitted += 1
+        with span("admit", request_id=req.request_id):
+            if self.kv_mode == "paged":
+                self._admit_paged(slot, req, plan or _MatchPlan())
+                return
+            prompt = jnp.asarray(req.prompt, jnp.int32)[None]      # (1, P)
+            logits, one = self._prefill(self.params, prompt)
+            tok = self._sample(logits[:, -1])                      # (1,)
+            self.cache = kvcache.cache_insert_slot(self.cache, one, slot.index)
+            self.last_tokens = self.last_tokens.at[slot.index].set(tok[0])
+            self.out_buf = self.out_buf.at[slot.index, 0].set(tok[0])
+            self.out_pos = self.out_pos.at[slot.index].set(1)
+            slot.to(SlotState.ADMITTING).to(SlotState.ACTIVE)  # monolithic prefill
+            slot.req = req
+            slot.n_out = 1
+            slot.admitted_step = self.steps
+            slot.submitted_step = req.submit_step
+            slot.active_since = self.steps
+            self._active_sessions.add(req.session)
+            self.prefill_tokens += int(prompt.shape[1])
+            self.admitted += 1
 
     def _admit_paged(self, slot: Slot, req: _Request, plan: _MatchPlan) -> None:
         """Begin a chunked admission.  With no resident prefix the slot's
@@ -1198,21 +1201,22 @@ class DecodeScheduler:
         the calibrated obj_read/obj_write latency + Table-4 cost models."""
         return self.blob_store.drain_ops()
 
-    def _prepare_write_span(self, slot: Slot, pos0: int, count: int) -> None:
+    def _prepare_write_span(self, slot: Slot, pos0: int, count: int) -> int:
         """Make the pages under ``[pos0, pos0 + count)`` writable for this
         slot: map unmapped pages (alloc-on-write, within the reservation)
         and copy-on-write split any mapped page that another reference
         still reads — the writer gets a private copy on a fresh page and
         remaps only its own table row, so the prefix index / parked journal
-        / sibling slot keeps reading the original bytes."""
-        changed = False
+        / sibling slot keeps reading the original bytes.  Returns the number
+        of pages it mapped."""
+        mapped = 0
         hi = min((pos0 + count - 1) // self.page_size, self.max_pages - 1)
         for pidx in range(pos0 // self.page_size, hi + 1):
             pid = int(self._page_rows[slot.index, pidx])
             if pid < 0:
                 if len(slot.pages) < slot.need:
                     self._map_page(slot, pidx)
-                    changed = True
+                    mapped += 1
                 # else: reservation exhausted — the dangling final write
                 # past it scatters out of bounds and is dropped
             elif self.allocator.refcount(pid) > 1:
@@ -1233,48 +1237,50 @@ class DecodeScheduler:
                 self.allocator.release([pid])
                 self._page_rows[slot.index, pidx] = new
                 self.cow_splits += 1
-                changed = True
-        if changed:
+                mapped += 1
+        if mapped:
             self.cache = kvcache.set_page_row(
                 self.cache, slot.index, self._page_rows[slot.index])
+        return mapped
 
     def _run_chunk(self, slot: Slot) -> None:
         """One prefill chunk for one admitting slot (alloc-on-write: map the
         pages the chunk's span touches — CoW-splitting any shared boundary
         page — then a B=1 forward against the shared pool).  The final
         chunk's logits seed the slot's first token."""
-        chunk = slot.chunks[slot.chunk_i]
-        C = len(chunk)
-        pos0 = slot.len
-        if self._has_kv:
-            self._prepare_write_span(slot, pos0, C)
-        logits, self.cache = self._chunk(
-            self.params, self.cache, jnp.asarray(chunk)[None], slot.index)
-        slot.len += C
-        slot.chunk_i += 1
-        self.prefill_tokens += C
-        self.prefill_chunks += 1
-        if slot.chunk_i == len(slot.chunks):
-            tok = self._sample(logits[:, -1])
-            self.last_tokens = self.last_tokens.at[slot.index].set(tok[0])
-            self.out_buf = self.out_buf.at[slot.index, 0].set(tok[0])
-            self.out_pos = self.out_pos.at[slot.index].set(1)
-            slot.to(SlotState.ACTIVE)
-            slot.active_since = self.steps
-            slot.n_out = 1
-            slot.chunks = None
-            self.admitted += 1
-            if self._spec:
-                # one host sync per admission: the draft starts from scratch
-                # on the full canonical stream (prompt + first sampled token)
-                slot.spec_last = int(tok[0])
-                slot.spec_pending = [int(t) for t in
-                                     np.asarray(slot.req.prompt,
-                                                np.int32).reshape(-1)]
-                slot.spec_pending.append(slot.spec_last)
-                slot.draft_len = 0
-                self.draft_cache["length"] = (
-                    self.draft_cache["length"].at[slot.index].set(0))
+        with span("chunk", request_id=slot.req.request_id):
+            chunk = slot.chunks[slot.chunk_i]
+            C = len(chunk)
+            pos0 = slot.len
+            if self._has_kv:
+                self._prepare_write_span(slot, pos0, C)
+            logits, self.cache = self._chunk(
+                self.params, self.cache, jnp.asarray(chunk)[None], slot.index)
+            slot.len += C
+            slot.chunk_i += 1
+            self.prefill_tokens += C
+            self.prefill_chunks += 1
+            if slot.chunk_i == len(slot.chunks):
+                tok = self._sample(logits[:, -1])
+                self.last_tokens = self.last_tokens.at[slot.index].set(tok[0])
+                self.out_buf = self.out_buf.at[slot.index, 0].set(tok[0])
+                self.out_pos = self.out_pos.at[slot.index].set(1)
+                slot.to(SlotState.ACTIVE)
+                slot.active_since = self.steps
+                slot.n_out = 1
+                slot.chunks = None
+                self.admitted += 1
+                if self._spec:
+                    # one host sync per admission: the draft starts from scratch
+                    # on the full canonical stream (prompt + first sampled token)
+                    slot.spec_last = int(tok[0])
+                    slot.spec_pending = [int(t) for t in
+                                         np.asarray(slot.req.prompt,
+                                                    np.int32).reshape(-1)]
+                    slot.spec_pending.append(slot.spec_last)
+                    slot.draft_len = 0
+                    self.draft_cache["length"] = (
+                        self.draft_cache["length"].at[slot.index].set(0))
 
     # -- decode loop ---------------------------------------------------------------
 
@@ -1430,55 +1436,70 @@ class DecodeScheduler:
         draft-and-verify round — over the active slots; returns the requests
         that completed this step (their slots are refilled from the pending
         list before returning)."""
-        self._fill_slots()
-        admitting = [s for s in self.slots if s.state is SlotState.ADMITTING]
-        if admitting:
-            pick = admitting[self._chunk_rr % len(admitting)]
-            self._chunk_rr += 1
-            self._run_chunk(pick)
-        restoring = [s for s in self.slots if s.state is SlotState.RESTORING]
-        if restoring:
-            pick = restoring[self._restore_rr % len(restoring)]
-            self._restore_rr += 1
-            self._run_restore_chunk(pick)
-        active = [s.index for s in self.slots if s.decoding]
-        if not active:
-            return []
-        if self._spec:
-            self._spec_round(active)
-        else:
-            if self.kv_mode == "paged" and self._has_kv:
-                # alloc-on-write for decode growth: make the page this step's
-                # token write lands in writable — map it if unmapped (within
-                # the reservation; the final step's dangling write past it is
-                # dropped by the unmapped table), CoW-split it if shared
+        with span("step", step=self.steps):
+            self._fill_slots()
+            admitting = [s for s in self.slots if s.state is SlotState.ADMITTING]
+            if admitting:
+                pick = admitting[self._chunk_rr % len(admitting)]
+                self._chunk_rr += 1
+                self._run_chunk(pick)
+            restoring = [s for s in self.slots if s.state is SlotState.RESTORING]
+            if restoring:
+                pick = restoring[self._restore_rr % len(restoring)]
+                self._restore_rr += 1
+                self._run_restore_chunk(pick)
+            active = [s.index for s in self.slots if s.decoding]
+            if not active:
+                return []
+            if self._spec:
+                self._spec_round(active)
+            else:
+                if self.kv_mode == "paged" and self._has_kv:
+                    # alloc-on-write for decode growth: make the page this step's
+                    # token write lands in writable — map it if unmapped (within
+                    # the reservation; the final step's dangling write past it is
+                    # dropped by the unmapped table), CoW-split it if shared
+                    with span("grow") as ids:
+                        mapped = 0
+                        for i in active:
+                            st = self.slots[i]
+                            mapped += self._prepare_write_span(st, st.len, 1)
+                        if ids is not None:
+                            ids["pages"] = mapped
+                with span("decode"):
+                    mask = np.zeros((self.n_slots,), bool)
+                    mask[active] = True
+                    self._key, sub = jax.random.split(self._key)
+                    self.cache, self.last_tokens, self.out_buf, self.out_pos = \
+                        self._decode(
+                            self.params, self.cache, self.last_tokens, self.out_buf,
+                            self.out_pos, jnp.asarray(mask), sub)
+                self.decode_tokens += len(active)
                 for i in active:
                     st = self.slots[i]
-                    self._prepare_write_span(st, st.len, 1)
-            mask = np.zeros((self.n_slots,), bool)
-            mask[active] = True
-            self._key, sub = jax.random.split(self._key)
-            self.cache, self.last_tokens, self.out_buf, self.out_pos = \
-                self._decode(
-                    self.params, self.cache, self.last_tokens, self.out_buf,
-                    self.out_pos, jnp.asarray(mask), sub)
-            self.decode_tokens += len(active)
-            for i in active:
-                st = self.slots[i]
-                st.n_out += 1
-                if self.kv_mode == "paged":
-                    st.len += 1
-        self.steps += 1
-        self.slot_steps += len(active)
-        if self.kv_mode == "paged" and self._has_kv:
-            self.page_step_sum += self.allocator.in_use
-        finished: List[CompletedRequest] = []
-        for i in active:
-            st = self.slots[i]
-            if st.n_out >= st.req.max_new:
+                    st.n_out += 1
+                    if self.kv_mode == "paged":
+                        st.len += 1
+            self.steps += 1
+            self.slot_steps += len(active)
+            if self.kv_mode == "paged" and self._has_kv:
+                self.page_step_sum += self.allocator.in_use
+            return self._finish(active)
+
+    def _finish(self, active: List[int]) -> List[CompletedRequest]:
+        """Hand back the requests whose slots reached ``max_new`` this step:
+        read each one's tokens from the output ring (a blocking device-to-host
+        read), park or release its slot, then refill the freed slots."""
+        with span("finish") as ids:
+            done = [self.slots[i] for i in active
+                    if self.slots[i].n_out >= self.slots[i].req.max_new]
+            if ids is not None:
+                ids["request_id"] = [st.req.request_id for st in done]
+            finished: List[CompletedRequest] = []
+            for st in done:
                 req = st.req
                 st.to(SlotState.DRAINED)
-                tokens = np.asarray(self.out_buf[i, : req.max_new])
+                tokens = np.asarray(self.out_buf[st.index, : req.max_new])
                 finished.append(CompletedRequest(
                     session=req.session, request_id=req.request_id,
                     tokens=tokens,
@@ -1497,8 +1518,8 @@ class DecodeScheduler:
                     self._release_slot(st)
                 self._active_sessions.discard(req.session)
                 self.completed += 1
-        if finished:
-            self._fill_slots()
+            if finished:
+                self._fill_slots()
         return finished
 
     def reset(self, *, clear_blob_store: bool = True) -> None:
