@@ -59,9 +59,25 @@ def _drive_workload(cloud, frontend, cfg, *, n_requests, sessions, prompt_len,
     return time.time() - t0
 
 
+def _warm_steps(sched, params, chunk_lens):
+    """Compile the scheduler's chunk and decode steps outside the billed
+    clock.  Both donate the cache they are given, so they run on a copy and
+    the scheduler's own cache stays live."""
+    import jax
+    import jax.numpy as jnp
+
+    def spare():
+        return jax.tree_util.tree_map(jnp.copy, sched.cache)
+
+    for C in chunk_lens:
+        sched._chunk(params, spare(), jnp.zeros((1, C), jnp.int32), 0)
+    sched._decode(params, spare(), sched.last_tokens, sched.out_buf,
+                  sched.out_pos, jnp.ones((sched.n_slots,), bool),
+                  jax.random.key(0))
+
+
 def _measure(mode, cfg, model, params, *, n_requests, sessions, prompt_len,
              max_new, batch_size):
-    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -77,15 +93,13 @@ def _measure(mode, cfg, model, params, *, n_requests, sessions, prompt_len,
     # pre-warm every jit shape the workload can hit, outside the billed clock
     if frontend.scheduler is not None:
         sched = frontend.scheduler
+        chunks = ()
         if sched.kv_mode == "ring":
             sched._prefill(params, jnp.zeros((1, prompt_len), jnp.int32))
         else:
-            for C in {min(PREFILL_CHUNK, prompt_len),
-                      prompt_len % PREFILL_CHUNK or PREFILL_CHUNK}:
-                sched._chunk(params, sched.cache, jnp.zeros((1, C), jnp.int32), 0)
-        sched._decode(params, sched.cache, sched.last_tokens, sched.out_buf,
-                      sched.out_pos, jnp.ones((sched.n_slots,), bool),
-                      jax.random.key(0))
+            chunks = {min(PREFILL_CHUNK, prompt_len),
+                      prompt_len % PREFILL_CHUNK or PREFILL_CHUNK}
+        _warm_steps(sched, params, chunks)
     else:
         for b in range(1, batch_size + 1):
             frontend.model_fn([np.zeros(prompt_len, np.int32)] * b)
@@ -446,21 +460,11 @@ def _fleet_cost_cell(cfg, model, params, *, prompt_len=16, max_new=8):
     day of retention on the parked state; the provisioned baseline pays
     the VM whether requests arrive or not.
     """
-    import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from repro.core import SimCloud
     from repro.core.cost import VM_DAILY, page_blob_retention_cost
     from repro.launch.serve import build_frontend, spawn_workload
-
-    def _warm(sched):
-        sched._chunk(params, sched.cache,
-                     jnp.zeros((1, min(PREFILL_CHUNK, prompt_len)), jnp.int32),
-                     0)
-        sched._decode(params, sched.cache, sched.last_tokens, sched.out_buf,
-                      sched.out_pos, jnp.ones((sched.n_slots,), bool),
-                      jax.random.key(0))
 
     def _serve(fleet_n):
         cloud = SimCloud(seed=0)
@@ -473,7 +477,7 @@ def _fleet_cost_cell(cfg, model, params, *, prompt_len=16, max_new=8):
         scheds = (fe.fleet._all_scheds() if fe.fleet is not None
                   else [fe.scheduler])
         for sched in scheds:            # pre-warm outside the billed clock
-            _warm(sched)
+            _warm_steps(sched, params, [min(PREFILL_CHUNK, prompt_len)])
         spawn_workload(cloud, fe, vocab=cfg.vocab, n_requests=FLEET_REQUESTS,
                        sessions=FLEET_SESSIONS, prompt_len=prompt_len,
                        max_new=max_new)

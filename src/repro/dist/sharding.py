@@ -268,7 +268,7 @@ def batch_shardings(batch_abs: PyTree, mesh) -> PyTree:
 # decode-cache kv-ring leaf keys; dims are indexed from the right so stacked
 # (leading layer dim) and unstacked leaves share one rule
 _CACHE_KV_KEYS = frozenset({"k", "v", "xk", "xv"})
-# paged-pool leaf keys: (..., n_pages, page_size, H, D) shared across slots
+# paged-pool leaf keys: (..., n_pages, page_size, H*D) shared across slots
 _CACHE_POOL_KEYS = frozenset({"kp", "vp"})
 
 
@@ -278,10 +278,10 @@ def cache_shardings(cache_abs: PyTree, mesh) -> PyTree:
     kv rings (..., B, T, H, D): batch on dp; heads on model when the head
     count divides, else fall back to the time dim (GQA archs with few kv
     heads — the divisibility guard the sharding tests pin).  Paged pools
-    (..., n_pages, page_size, H, D) have no slot axis — every slot's page
+    (..., n_pages, page_size, H*D) have no slot axis — every slot's page
     table indexes one shared pool, so the pool stays *replicated over dp*
-    and shards its within-page lane dim on model (heads, then pages, as
-    fallbacks — the paged-attention kernel slices one page per grid step
+    and shards its within-page lane dim on model (heads x head_dim, then
+    pages, as fallbacks — the paged-attention kernel slices one page per grid step
     by table index, which head- or page-sharded pools can only serve by
     all-gathering the pool);
     page tables (..., n_slots, max_pages) follow the slot batch onto dp.
@@ -310,7 +310,7 @@ def cache_shardings(cache_abs: PyTree, mesh) -> PyTree:
                 return True
             return False
 
-        if key in _CACHE_POOL_KEYS and nd >= 4:  # (..., Np, ps, H, D) shared pool
+        if key in _CACHE_POOL_KEYS and nd >= 3:  # (..., Np, ps, H*D) shared pool
             # within-page lane dim first, then heads, then pages.  The paged
             # decode kernel streams the pool one page block per grid step,
             # so a pool sharded across heads or pages turns every
@@ -320,7 +320,7 @@ def cache_shardings(cache_abs: PyTree, mesh) -> PyTree:
             # head-sharded, 93 MB lane-sharded).  Lane shards keep block
             # slicing local and partition the softmax like the ring cells'
             # seq-sharded attention; the gather path is layout-indifferent.
-            put(-3, rules.model) or put(-2, rules.model) or put(-4, rules.model)
+            put(-2, rules.model) or put(-1, rules.model) or put(-3, rules.model)
         elif key == "page_table" and nd >= 2:    # (..., n_slots, max_pages)
             put(-2, dp)
         elif key in _CACHE_KV_KEYS and nd >= 4:  # (..., B, T, H, D)
@@ -346,14 +346,14 @@ def offload_stage_shardings(stage_abs: PyTree, mesh) -> PyTree:
     """Placement for KV offload staging buffers.
 
     A staging buffer is a gathered page chunk ``(..., n_chunk_pages,
-    page_size, H, D)`` in flight between the shared pool and host memory
+    page_size, H*D)`` in flight between the shared pool and host memory
     (``kvcache.gather_pages`` / ``scatter_pages``).  Unlike the resident
     pool, the chunk is about to cross the device boundary, so the only
     useful partitioning is the one that matches the pool's own sharding —
     each shard DMAs its own pool slice and no reshuffle happens before the
     transfer.  That means the *same* fallback order as
     :func:`cache_shardings`' pool rule: within-page lane dim on ``model``
-    first, then heads; everything else (including the gathered-page dim —
+    first, then heads x head_dim; everything else (including the gathered-page dim —
     chunks are a handful of pages, far too small to amortize a collective)
     stays replicated.
     """
@@ -364,9 +364,9 @@ def offload_stage_shardings(stage_abs: PyTree, mesh) -> PyTree:
         shape = tuple(leaf.shape)
         nd = len(shape)
         entries: list = [None] * nd
-        if keys and keys[-1] in _CACHE_POOL_KEYS and nd >= 4:
+        if keys and keys[-1] in _CACHE_POOL_KEYS and nd >= 3:
             msize = _axes_size(rules.model, mesh)
-            for dim in (nd - 3, nd - 2):    # (..., n, ps, H, D): lane, heads
+            for dim in (nd - 2, nd - 1):    # (..., n, ps, H*D): lane, heads
                 if shape[dim] % msize == 0:
                     entries[dim] = rules.model
                     break

@@ -84,12 +84,15 @@ def _cache_bytes(sched) -> int:
 def _decode_step_stats(sched, *, reps=20):
     """Steady-state decode dispatch: wall latency (solo, post-warm) and the
     compiled step's per-device collective wire bytes."""
-    args = _decode_args(sched)
-    jax.block_until_ready(sched._decode(*args))          # warm
+    params, _, *rest = _decode_args(sched)
+    # the step donates its cache: each call takes the one the last returned
+    sched.cache = jax.block_until_ready(
+        sched._decode(params, sched.cache, *rest))[0]     # warm
     times = []
     for _ in range(reps):
         t0 = time.perf_counter()
-        jax.block_until_ready(sched._decode(*args))
+        sched.cache = jax.block_until_ready(
+            sched._decode(params, sched.cache, *rest))[0]
         times.append((time.perf_counter() - t0) * 1e3)
     wire, kinds = _decode_wire_bytes(sched)
     return {

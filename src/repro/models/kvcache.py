@@ -12,9 +12,14 @@ sequence is 3 tokens or 3000.
 
 **Paged pool** (continuous-batching serving).  One shared pool per layer plus
 a per-slot page table:
-  kp, vp     : (n_pages, page_size, Hkv, D)  shared block pool
+  kp, vp     : (n_pages, page_size, Hkv*D)   shared block pool
   page_table : (B, max_pages) int32          slot's logical->physical map
                                              (-1 = unmapped)
+A token's Hkv x D values are one minor dim.  The TPU lays an array out to
+avoid padding its minor dims: with ``(..., Hkv, D)`` minor and ``D`` under
+128 (minicpm-2b: 36 x 64) it would store the page dim minor-most, where a
+page is no contiguous block and no gather or scatter by page runs in place —
+every step would convert the whole pool to page-major order and back.
 Token at absolute position ``p`` of slot ``b`` lives at
 ``kp[page_table[b, p // page_size], p % page_size]``.  Pages are handed out
 by the host-side :class:`PageAllocator` (alloc-on-write, free-on-completion),
@@ -25,9 +30,13 @@ Writes to unmapped pages are dropped (the physical index is pushed out of
 bounds and JAX scatters drop OOB updates), so a freed slot's stale decode
 traffic can never corrupt a page that now belongs to another slot.
 
-Both layouts are stacked over layers (leading L dim) so decode can
-``lax.scan`` the layer stack; the page table is replicated per layer (int32,
-negligible) so the scan carries one pytree.  ``pos`` may be a scalar or a
+Both layouts are stacked over layers (leading L dim).  The layer scan
+carries the stacked leaves whole and addresses its own layer by index
+(``layer=`` on :func:`cache_update_layer` / :func:`cache_kv_view`): each
+layer scatters its tokens at ``(layer, ...)`` and gathers its view back, so
+no layer's slice of the cache is copied out and a donated cache is updated in
+place.  ``layer=None`` takes one unstacked layer.  The page table is
+replicated per layer (int32, negligible).  ``pos`` may be a scalar or a
 ``(B,)`` vector exactly as before.
 
 The paged view gathers pages in *logical* order, so when no ring wrap has
@@ -80,22 +89,38 @@ def cache_capacity(layer_cache: Dict) -> int:
     """Static token capacity of one row of a layer cache (ring T, or the
     page table's logical span for the pool)."""
     if is_paged(layer_cache):
-        return layer_cache["page_table"].shape[-1] * layer_cache["kp"].shape[-3]
+        return layer_cache["page_table"].shape[-1] * layer_cache["kp"].shape[-2]
     return layer_cache["k"].shape[-3]
 
 
+def _at(layer) -> tuple:
+    """Index prefix addressing one layer of a stacked leaf (none unstacked)."""
+    return () if layer is None else (layer,)
+
+
+def layer_view(layer_cache: Dict, layer) -> Dict:
+    """One layer's (unstacked) slice of a stacked layer cache; ``layer=None``
+    passes an unstacked cache through."""
+    if layer is None:
+        return layer_cache
+    return {k: v[layer] for k, v in layer_cache.items()}
+
+
 def cache_update_layer(layer_cache: Dict, k_new: jnp.ndarray, v_new: jnp.ndarray,
-                       pos: jnp.ndarray) -> Dict:
+                       pos: jnp.ndarray, layer=None) -> Dict:
     """Insert S_new tokens at absolute position ``pos``.
 
     Ring layout scatters into per-row ring slots (``pos % T``); paged layout
     routes each token through the page table into the shared pool.
     layer_cache k/v or kp/vp as documented above; k_new/v_new: (B, S, Hkv, D).
     ``pos`` scalar (lockstep batch) or (B,) (per-slot continuous batching).
+    ``layer`` (int or traced scalar) scatters into that layer's rows of a
+    stacked cache and returns the whole stacked leaves.
     """
     if is_paged(layer_cache):
-        return _paged_update_layer(layer_cache, k_new, v_new, pos)
-    T = layer_cache["k"].shape[1]
+        return _paged_update_layer(layer_cache, k_new, v_new, pos, layer)
+    k, v, positions = layer_cache["k"], layer_cache["v"], layer_cache["positions"]
+    T = k.shape[-3]
     B, S = k_new.shape[0], k_new.shape[1]
     pos = jnp.asarray(pos, jnp.int32)
     if S > T:
@@ -106,27 +131,18 @@ def cache_update_layer(layer_cache: Dict, k_new: jnp.ndarray, v_new: jnp.ndarray
         pos = pos + (S - T)
         S = T
     if pos.ndim == 0:
-        abs_pos = pos + jnp.arange(S, dtype=jnp.int32)        # (S,)
-        slots = abs_pos % T                                   # ring slots
-        k = layer_cache["k"].at[:, slots].set(k_new.astype(layer_cache["k"].dtype))
-        v = layer_cache["v"].at[:, slots].set(v_new.astype(layer_cache["v"].dtype))
-        positions = layer_cache["positions"].at[:, slots].set(
-            jnp.broadcast_to(abs_pos[None, :], (B, S))
-        )
-    else:
-        abs_pos = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]  # (B, S)
-        slots = abs_pos % T                                   # per-row ring slots
-        b = jnp.arange(B, dtype=jnp.int32)[:, None]
-        k = layer_cache["k"].at[b, slots].set(k_new.astype(layer_cache["k"].dtype))
-        v = layer_cache["v"].at[b, slots].set(v_new.astype(layer_cache["v"].dtype))
-        positions = layer_cache["positions"].at[b, slots].set(abs_pos)
-    return {"k": k, "v": v, "positions": positions}
+        pos = jnp.broadcast_to(pos, (B,))
+    abs_pos = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]      # (B, S)
+    at = _at(layer) + (jnp.arange(B, dtype=jnp.int32)[:, None], abs_pos % T)
+    return {"k": k.at[at].set(k_new.astype(k.dtype)),
+            "v": v.at[at].set(v_new.astype(v.dtype)),
+            "positions": positions.at[at].set(abs_pos)}
 
 
 def _paged_update_layer(layer_cache: Dict, k_new: jnp.ndarray, v_new: jnp.ndarray,
-                       pos: jnp.ndarray) -> Dict:
+                        pos: jnp.ndarray, layer) -> Dict:
     kp, vp, pt = layer_cache["kp"], layer_cache["vp"], layer_cache["page_table"]
-    n_pages, page_size = kp.shape[-4], kp.shape[-3]
+    n_pages, page_size = kp.shape[-3], kp.shape[-2]
     max_pages = pt.shape[-1]
     B, S = k_new.shape[0], k_new.shape[1]
     pos = jnp.asarray(pos, jnp.int32)
@@ -135,31 +151,39 @@ def _paged_update_layer(layer_cache: Dict, k_new: jnp.ndarray, v_new: jnp.ndarra
     abs_pos = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]      # (B, S)
     page_idx = abs_pos // page_size
     offset = abs_pos % page_size
-    pid = jnp.take_along_axis(pt, jnp.clip(page_idx, 0, max_pages - 1), axis=-1)
+    row = pt if layer is None else pt[layer]
+    pid = jnp.take_along_axis(row, jnp.clip(page_idx, 0, max_pages - 1), axis=-1)
     # unmapped / out-of-table positions are pushed out of bounds: JAX drops
     # OOB scatter updates, so stale traffic from freed or admitting slots can
     # never land in a page it does not own.
     pid = jnp.where((page_idx < max_pages) & (pid >= 0), pid, n_pages)
-    kp = kp.at[pid, offset].set(k_new.astype(kp.dtype))
-    vp = vp.at[pid, offset].set(v_new.astype(vp.dtype))
+    at = _at(layer) + (pid, offset)
+    kp = kp.at[at].set(k_new.reshape(B, S, -1).astype(kp.dtype))
+    vp = vp.at[at].set(v_new.reshape(B, S, -1).astype(vp.dtype))
     return {"kp": kp, "vp": vp, "page_table": pt}
 
 
-def cache_kv_view(layer_cache: Dict, upto: Optional[jnp.ndarray] = None
+def cache_kv_view(layer_cache: Dict, upto: Optional[jnp.ndarray] = None,
+                  layer=None, n_kv: Optional[int] = None
                   ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Returns (k, v, kv_positions, kv_valid) for sdpa().
 
     Ring layout reads the buffers directly (``positions`` doubles as the
     validity mask).  Paged layout gathers the slot's pages from the pool in
-    logical order; ``upto`` (scalar or (B,) live length) is required there —
-    lanes at or past it, and lanes on unmapped pages, are masked invalid.
+    logical order; ``upto`` (scalar or (B,) live length) and ``n_kv`` (the
+    heads a token's Hkv*D values split into) are required there — lanes at
+    or past ``upto``, and lanes on unmapped pages, are masked invalid.
+    ``layer`` reads that layer of a stacked cache (the paged gather indexes
+    ``(layer, page)`` on the stacked pool).
     """
     if is_paged(layer_cache):
-        if upto is None:
-            raise ValueError("paged cache view needs `upto` (the live length)")
-        return _paged_kv_view(layer_cache, upto)
-    pos = layer_cache["positions"]
-    return layer_cache["k"], layer_cache["v"], pos, pos >= 0
+        if upto is None or n_kv is None:
+            raise ValueError("paged cache view needs `upto` (the live length) "
+                             "and `n_kv`")
+        return _paged_kv_view(layer_cache, upto, layer, n_kv)
+    ring = layer_view(layer_cache, layer)
+    pos = ring["positions"]
+    return ring["k"], ring["v"], pos, pos >= 0
 
 
 def paged_attn_decode(layer_cache: Dict, q: jnp.ndarray, pos, *,
@@ -175,7 +199,7 @@ def paged_attn_decode(layer_cache: Dict, q: jnp.ndarray, pos, *,
     page table — the gathered (B, T, Hkv, D) cache never materializes in
     HBM.  Read-only: ``_prepare_write_span`` / ``cache_update_layer`` still
     own every pool write, so CoW splits and ``mask_slot_rows`` freezing are
-    untouched.
+    untouched.  ``layer_cache`` is one layer's (unstacked).
 
     ``pos`` is the slot's live length pre-write (scalar or (B,)).  With
     ``k_new``/``v_new`` the just-projected token is appended in fp32 on top
@@ -188,6 +212,8 @@ def paged_attn_decode(layer_cache: Dict, q: jnp.ndarray, pos, *,
     from ..kernels.paged_attention import paged_attention
 
     kp, vp, pt = layer_cache["kp"], layer_cache["vp"], layer_cache["page_table"]
+    # the kernel reads (n_pages, page_size, Hkv, D) pages
+    kp, vp = (x.reshape(x.shape[:-1] + (-1, q.shape[-1])) for x in (kp, vp))
     B = q.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
     if pos.ndim == 0:
@@ -208,19 +234,20 @@ def paged_attn_decode(layer_cache: Dict, q: jnp.ndarray, pos, *,
     return constrain(out, "attn_out")
 
 
-def _paged_kv_view(layer_cache: Dict, upto) -> Tuple[jnp.ndarray, ...]:
+def _paged_kv_view(layer_cache: Dict, upto, layer, n_kv: int) -> Tuple[jnp.ndarray, ...]:
     kp, vp, pt = layer_cache["kp"], layer_cache["vp"], layer_cache["page_table"]
-    n_pages, page_size, n_kv, head_dim = kp.shape[-4:]
+    n_pages, page_size, width = kp.shape[-3:]
     B, max_pages = pt.shape[-2:]
     T = max_pages * page_size
-    pid = jnp.clip(pt, 0, n_pages - 1)
-    k = kp[pid].reshape(B, T, n_kv, head_dim)
-    v = vp[pid].reshape(B, T, n_kv, head_dim)
+    row = pt if layer is None else pt[layer]
+    at = _at(layer) + (jnp.clip(row, 0, n_pages - 1),)
+    k = kp[at].reshape(B, T, n_kv, width // n_kv)
+    v = vp[at].reshape(B, T, n_kv, width // n_kv)
     kv_pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
     upto = jnp.asarray(upto, jnp.int32)
     if upto.ndim == 0:
         upto = jnp.broadcast_to(upto, (B,))
-    mapped = jnp.repeat(pt >= 0, page_size, axis=-1)                      # (B, T)
+    mapped = jnp.repeat(row >= 0, page_size, axis=-1)                     # (B, T)
     return k, v, kv_pos, mapped & (kv_pos < upto[:, None])
 
 
@@ -510,7 +537,7 @@ def batched_cache(model, n_slots: int, seq_len: int) -> Dict:
 def paged_cache(model, n_slots: int, *, page_size: int, n_pages: int,
                 max_pages: int) -> Dict:
     """A paged decode cache: every KV ring of the model's batch cache is
-    replaced by a shared ``(n_pages, page_size, Hkv, D)`` pool plus a
+    replaced by a shared ``(n_pages, page_size, Hkv*D)`` pool plus a
     per-slot ``(n_slots, max_pages)`` page table (replicated across the
     stacked layer dim so the decode scan carries one pytree).  Ring-free
     state (SSM/RG-LRU recurrences, conv tails) keeps its per-slot layout."""
@@ -521,8 +548,9 @@ def paged_cache(model, n_slots: int, *, page_size: int, n_pages: int,
             lead = k.shape[:-4]
             out = {kk: transform(vv) for kk, vv in tree.items()
                    if kk not in ("k", "v", "positions")}
-            out["kp"] = jnp.zeros(lead + (n_pages, page_size) + k.shape[-2:], k.dtype)
-            out["vp"] = jnp.zeros(lead + (n_pages, page_size) + k.shape[-2:], k.dtype)
+            pool = lead + (n_pages, page_size, k.shape[-2] * k.shape[-1])
+            out["kp"] = jnp.zeros(pool, k.dtype)
+            out["vp"] = jnp.zeros(pool, k.dtype)
             out["page_table"] = -jnp.ones(lead + (n_slots, max_pages), jnp.int32)
             return out
         if isinstance(tree, dict):
@@ -621,16 +649,16 @@ def set_page_row(batch_cache: Dict, slot: int, row) -> Dict:
 # Page-level offload: extract / inject pool pages (storage-backed preemption)
 # ---------------------------------------------------------------------------
 
-# Axis of the page dim in a pool leaf (..., n_pages, page_size, Hkv, D).
-_PAGE_AXIS = -4
+# Axis of the page dim in a pool leaf (..., n_pages, page_size, Hkv*D).
+_PAGE_AXIS = -3
 
 
 def gather_pages(cache: Dict, page_ids) -> Dict:
     """Extract physical pages ``page_ids`` from every pool leaf.
 
     Returns a pytree with the cache's structure restricted to pool leaves:
-    each ``kp``/``vp`` leaf becomes ``(..., len(page_ids), page_size, Hkv,
-    D)`` — the staging buffer a preemption ships to the object store.  The
+    each ``kp``/``vp`` leaf becomes ``(..., len(page_ids), page_size,
+    Hkv*D)`` — the staging buffer a preemption ships to the object store.  The
     caller supplies ``page_ids`` in *logical* order (the slot's page-table
     order), so a blob is position-ordered regardless of how scrambled the
     physical table is.  Exact inverse of :func:`scatter_pages` through any
@@ -761,8 +789,8 @@ def kv_bytes_per_token(cache: Dict) -> int:
     def visit(path, leaf):
         nonlocal total
         key = _path_keys(path)[-1]
-        if key in POOL_KEYS:            # (..., Np, ps, H, D)
-            tokens = leaf.shape[-4] * leaf.shape[-3]
+        if key in POOL_KEYS:            # (..., Np, ps, H*D)
+            tokens = leaf.shape[-3] * leaf.shape[-2]
             total += leaf.size * leaf.dtype.itemsize // tokens
         elif key in ("k", "v"):         # (..., B, T, H, D)
             per_row_tokens = leaf.shape[-3]

@@ -26,7 +26,7 @@ from ..dist import sharding as shd
 from . import layers
 from .config import ArchConfig
 from .layers import cast
-from .transformer import DenseLM
+from .transformer import DenseLM, attention_decode
 
 
 # ---------------------------------------------------------------------------
@@ -310,30 +310,11 @@ class MoELM(DenseLM):
         logits = layers.lm_head(params["embedding"], cfg, x)
         return shd.constrain(logits, "logits"), aux * self._aux_weight
 
-    def _layer_decode(self, p, x, layer_cache, pos):
-        from . import kvcache
+    def _layer_decode(self, p, x, layer_cache, pos, layer=None):
         cfg = self.cfg
         rs = jnp.asarray(cfg.residual_scale, x.dtype)
-        B, S = x.shape[0], x.shape[1]
-        positions = kvcache.decode_positions(pos, B, S)
         h = layers.apply_norm(cfg.norm, p["attn_norm"], x)
-        q, k, v = layers.qkv_project(p["attn"], cfg, h, positions)
-        new_cache = kvcache.cache_update_layer(layer_cache, k, v, pos)
-        if (S == 1 and cfg.attn_backend == "paged_kernel"
-                and kvcache.is_paged(layer_cache)):
-            # fused table-indirect kernel: pre-update pool + fp32 append
-            o = kvcache.paged_attn_decode(layer_cache, q, pos,
-                                          window=cfg.sliding_window,
-                                          k_new=k, v_new=v)
-        else:
-            # S=1 rides the chunk path (post-update view) so decode-written
-            # KV is bitwise prefill KV — see dense_layer_decode.
-            ck, cv, kv_pos, kv_valid = kvcache.cache_kv_view(new_cache, upto=pos + S)
-            o = layers.sdpa(q, ck, cv, causal=True, window=cfg.sliding_window,
-                            q_positions=positions, kv_positions=kv_pos,
-                            kv_valid=kv_valid)
-        o = o.reshape(B, S, cfg.n_heads * cfg.the_head_dim())
-        h = jnp.einsum("bsq,qd->bsd", o, layers.wcast(p["attn"]["wo"], "row"))
+        h, new_cache = attention_decode(p["attn"], cfg, h, layer_cache, pos, layer)
         x = x + h * rs
         h = layers.apply_norm(cfg.norm, p["mlp_norm"], x)
         h, _ = moe_ffn(p["moe"], cfg, h, no_drop=True)

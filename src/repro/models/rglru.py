@@ -214,7 +214,8 @@ def _layer_step(p: Dict, cfg: ArchConfig, kind: str, x: jnp.ndarray,
                                               window=cfg.hybrid.local_window,
                                               include_new=True)
             else:
-                ck, cv, kv_pos, kv_valid = kvcache.cache_kv_view(new_lc, upto=pos + S)
+                ck, cv, kv_pos, kv_valid = kvcache.cache_kv_view(
+                    new_lc, upto=pos + S, n_kv=cfg.n_kv_heads)
                 o = layers.sdpa(q, ck, cv, causal=True, window=cfg.hybrid.local_window,
                                 q_positions=positions, kv_positions=kv_pos, kv_valid=kv_valid)
             o = o.reshape(B, S, cfg.n_heads * cfg.the_head_dim())

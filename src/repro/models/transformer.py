@@ -54,18 +54,17 @@ def dense_layer_fwd(p: Dict, cfg: ArchConfig, x: jnp.ndarray,
     return constrain(x, "activation")
 
 
-def dense_layer_decode(p: Dict, cfg: ArchConfig, x: jnp.ndarray,
-                       layer_cache: Dict, pos: jnp.ndarray) -> Tuple[jnp.ndarray, Dict]:
-    """One-token (or short-S) step against a ring or paged cache.
-
-    ``pos`` scalar (lockstep batch) or (B,) per-slot (continuous batching).
+def attention_decode(p: Dict, cfg: ArchConfig, h: jnp.ndarray, layer_cache: Dict,
+                     pos: jnp.ndarray, layer=None) -> Tuple[jnp.ndarray, Dict]:
+    """Attention half of a decode layer against a ring or paged cache:
+    project, write the new K/V, attend, project out.  Returns (output,
+    updated cache).  ``layer`` addresses one layer of a stacked cache (see
+    :mod:`repro.models.kvcache`); None takes one unstacked layer.
     """
-    rs = jnp.asarray(cfg.residual_scale, x.dtype)
-    B, S = x.shape[0], x.shape[1]
+    B, S = h.shape[0], h.shape[1]
     positions = kvcache.decode_positions(pos, B, S)
-    h = layers.apply_norm(cfg.norm, p["attn_norm"], x)
-    q, k, v = layers.qkv_project(p["attn"], cfg, h, positions)
-    new_cache = kvcache.cache_update_layer(layer_cache, k, v, pos)
+    q, k, v = layers.qkv_project(p, cfg, h, positions)
+    new_cache = kvcache.cache_update_layer(layer_cache, k, v, pos, layer)
     if S > kvcache.cache_capacity(layer_cache):
         # prefill-from-scratch longer than the (windowed) ring: the ring only
         # keeps the trailing window, so attend the fresh full-sequence k/v.
@@ -75,7 +74,7 @@ def dense_layer_decode(p: Dict, cfg: ArchConfig, x: jnp.ndarray,
         # fused path: stream the slot's pages via the table-indirect Pallas
         # kernel (pre-update pool + fp32 new-token append) — the gathered
         # cache never materializes in HBM.
-        o = kvcache.paged_attn_decode(layer_cache, q, pos,
+        o = kvcache.paged_attn_decode(kvcache.layer_view(layer_cache, layer), q, pos,
                                       window=cfg.sliding_window,
                                       k_new=k, v_new=v)
     else:
@@ -85,11 +84,24 @@ def dense_layer_decode(p: Dict, cfg: ArchConfig, x: jnp.ndarray,
         # ``sdpa_append`` formulation saved the read-after-write but made
         # decode-written KV diverge from prefill KV in low bf16 bits,
         # blocking generated-tail reuse and accept/reject speculation.)
-        ck, cv, kv_pos, kv_valid = kvcache.cache_kv_view(new_cache, upto=pos + S)
+        ck, cv, kv_pos, kv_valid = kvcache.cache_kv_view(
+            new_cache, upto=pos + S, layer=layer, n_kv=cfg.n_kv_heads)
         o = layers.sdpa(q, ck, cv, causal=True, window=cfg.sliding_window,
                         q_positions=positions, kv_positions=kv_pos, kv_valid=kv_valid)
     o = o.reshape(B, S, cfg.n_heads * cfg.the_head_dim())
-    h = jnp.einsum("bsq,qd->bsd", o, layers.wcast(p["attn"]["wo"], "row"))
+    return jnp.einsum("bsq,qd->bsd", o, layers.wcast(p["wo"], "row")), new_cache
+
+
+def dense_layer_decode(p: Dict, cfg: ArchConfig, x: jnp.ndarray,
+                       layer_cache: Dict, pos: jnp.ndarray, layer=None
+                       ) -> Tuple[jnp.ndarray, Dict]:
+    """One-token (or short-S) step against a ring or paged cache.
+
+    ``pos`` scalar (lockstep batch) or (B,) per-slot (continuous batching).
+    """
+    rs = jnp.asarray(cfg.residual_scale, x.dtype)
+    h = layers.apply_norm(cfg.norm, p["attn_norm"], x)
+    h, new_cache = attention_decode(p["attn"], cfg, h, layer_cache, pos, layer)
     x = x + h * rs
     h = layers.apply_norm(cfg.norm, p["mlp_norm"], x)
     h = layers.apply_mlp(p["mlp"], cfg, h)
@@ -124,8 +136,8 @@ class DenseLM:
     def _layer_fwd(self, p, x, positions):
         return dense_layer_fwd(p, self.cfg, x, positions)
 
-    def _layer_decode(self, p, x, layer_cache, pos):
-        return dense_layer_decode(p, self.cfg, x, layer_cache, pos)
+    def _layer_decode(self, p, x, layer_cache, pos, layer=None):
+        return dense_layer_decode(p, self.cfg, x, layer_cache, pos, layer)
 
     # -- stack runner ------------------------------------------------------------
 
@@ -185,34 +197,36 @@ class DenseLM:
 
     def decode_step(self, params: Dict, cache: Dict, tokens: jnp.ndarray
                     ) -> Tuple[jnp.ndarray, Dict]:
-        """tokens: (B, S_new) — one (or a few) new tokens per sequence."""
+        """tokens: (B, S_new) — one (or a few) new tokens per sequence.
+
+        The stacked cache rides the layer loop as carry, beside the layer
+        index: each layer writes its tokens at ``(layer, ...)`` and reads its
+        view back through one indexed gather.  No layer's slice of the cache
+        is copied out and no stacked copy is built, so a donated cache is
+        updated in place."""
         cfg = self.cfg
         x = layers.embed_tokens(params["embedding"], cfg, tokens)
         pos = cache["length"]
 
         def body(carry, layer_in):
-            h = carry
-            p, lc = layer_in
-            h, new_lc = self._layer_decode(p, h, lc, pos)
-            return h, new_lc
+            h, kv = carry
+            p, i = layer_in
+            return self._layer_decode(p, h, kv, pos, i), None
 
-        layer_keys = (("kp", "vp", "page_table") if "kp" in cache
-                      else ("k", "v", "positions"))
-        layer_caches = {k: cache[k] for k in layer_keys}
-        fn = remat_wrap(body, "none")
+        kv_keys = (("kp", "vp", "page_table") if "kp" in cache
+                   else ("k", "v", "positions"))
+        kv = {k: cache[k] for k in kv_keys}
         if cfg.scan_layers:
-            x, new_caches = jax.lax.scan(fn, x, (params["layers"], layer_caches))
+            (x, kv), _ = jax.lax.scan(
+                body, (x, kv),
+                (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
         else:
-            outs = []
             for i in range(cfg.n_layers):
                 p = jax.tree_util.tree_map(operator.itemgetter(i), params["layers"])
-                lc = jax.tree_util.tree_map(operator.itemgetter(i), layer_caches)
-                x, nc = fn(x, (p, lc))
-                outs.append(nc)
-            new_caches = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
+                (x, kv), _ = body((x, kv), (p, i))
         x = layers.apply_norm(cfg.norm, params["final_norm"], x)
         logits = layers.lm_head(params["embedding"], cfg, x)
-        new_cache = dict(new_caches)
+        new_cache = dict(kv)
         new_cache["length"] = cache["length"] + tokens.shape[1]
         return constrain(logits, "logits"), new_cache
 

@@ -210,11 +210,11 @@ def make_offload_steps(*, policy=None, cache_specs=None,
     ``extract(cache, page_ids)`` gathers the victim's pool pages (in the
     page table's logical order) into the staging buffer the scheduler ships
     to the object store; ``inject(cache, page_ids, blob)`` scatters a blob
-    chunk back onto freshly allocated pages during a chunked restore.  Both
-    are pure pool-pytree programs (:func:`repro.models.kvcache.gather_pages`
-    / :func:`scatter_pages`) jitted once and re-traced only per distinct
-    chunk length, so a restore step costs one dispatch — same budget as a
-    prefill chunk.
+    chunk back onto freshly allocated pages during a chunked restore (it
+    donates the cache it is given).  Both are pure pool-pytree programs
+    (:func:`repro.models.kvcache.gather_pages` / :func:`scatter_pages`)
+    jitted once and re-traced only per distinct chunk length, so a restore
+    step costs one dispatch — same budget as a prefill chunk.
 
     With a concrete-mesh ``policy`` plus the cache/stage PartitionSpec
     pytrees, both run under ``jax.shard_map``: the page dim of the pool is
@@ -226,7 +226,8 @@ def make_offload_steps(*, policy=None, cache_specs=None,
 
     mesh = getattr(policy, "mesh", None)
     if mesh is None or cache_specs is None or stage_specs is None:
-        return jax.jit(kvcache.gather_pages), jax.jit(kvcache.scatter_pages)
+        return (jax.jit(kvcache.gather_pages),
+                jax.jit(kvcache.scatter_pages, donate_argnums=(0,)))
     from jax.sharding import PartitionSpec as P
 
     def extract_body(cache, ids):
@@ -240,7 +241,7 @@ def make_offload_steps(*, policy=None, cache_specs=None,
         out_specs=stage_specs, check_vma=False))
     inject = jax.jit(jax.shard_map(
         inject_body, mesh=mesh, in_specs=(cache_specs, P(), stage_specs),
-        out_specs=cache_specs, check_vma=False))
+        out_specs=cache_specs, check_vma=False), donate_argnums=(0,))
     return extract, inject
 
 

@@ -9,7 +9,7 @@ requests.  Every slot runs the explicit lifecycle in
 
 Two KV layouts:
 
-* ``kv_mode='paged'`` (default): one shared ``(n_pages, page_size, Hkv, D)``
+* ``kv_mode='paged'`` (default): one shared ``(n_pages, page_size, Hkv*D)``
   pool per layer plus a per-slot page table
   (:func:`repro.models.kvcache.paged_cache`).  Pages are handed out by a
   host-side free list (:class:`repro.models.kvcache.PageAllocator`) —
@@ -198,6 +198,15 @@ class _MatchPlan:
     record: Optional[ParkedSession] = None
 
 
+def _kv_leaf(cache):
+    """The cache's first KV storage leaf (pool ``kp`` or ring ``k``); None
+    for ring-free state."""
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
+        if getattr(path[-1], "key", None) in ("kp", "k"):
+            return leaf
+    return None
+
+
 class DecodeScheduler:
     """Continuous batching over a shared paged pool (or per-slot rings)."""
 
@@ -287,9 +296,13 @@ class DecodeScheduler:
         self.index_journal_puts = 0
         self.index_adopted = 0
         self._parked: Dict[str, ParkedSession] = {}
-        self._copy_pages = jax.jit(kvcache.copy_pages)
+        # every program that takes the cache and returns it updated donates
+        # it (the caller rebinds ``self.cache`` to the result), so the pool
+        # is written in place instead of copied
+        self._copy_pages = jax.jit(kvcache.copy_pages, donate_argnums=(0,))
         self._gather_state = jax.jit(kvcache.gather_slot_state)
-        self._scatter_state = jax.jit(kvcache.scatter_slot_state)
+        self._scatter_state = jax.jit(kvcache.scatter_slot_state,
+                                      donate_argnums=(0,))
         self.shared_prefix_tokens = 0   # prompt tokens never re-prefilled
         self.park_hits = 0
         self.park_misses = 0
@@ -372,7 +385,8 @@ class DecodeScheduler:
         skw = (dict(policy=self._policy, cache_specs=self.cache_specs)
                if self._policy is not None else {})
         if kv_mode == "paged":
-            self._chunk = jax.jit(make_chunk_step(model, **skw))
+            self._chunk = jax.jit(make_chunk_step(model, **skw),
+                                  donate_argnums=(1,))
         else:
             ring_policy = self._policy
 
@@ -385,7 +399,11 @@ class DecodeScheduler:
             policy=self._policy, cache_specs=self.cache_specs,
             stage_specs=self.stage_specs)
 
-        self._decode = jax.jit(self._step_impl)
+        self._decode = jax.jit(self._step_impl, donate_argnums=(1,))
+        # decode and chunk dispatches whose old pool leaf was deleted at
+        # dispatch (donated) or left alive (the step wrote a copy)
+        self.pool_donated_steps = 0
+        self.pool_copied_steps = 0
 
         # -- draft-and-verify speculative decoding --------------------------
         # The draft proposes spec_k tokens per slot per round; the target
@@ -463,9 +481,11 @@ class DecodeScheduler:
             self._draft_catchup = jax.jit(
                 make_draft_catchup_step(draft_model, **dkw))
             self._draft_step = jax.jit(make_draft_step(draft_model, **dkw))
-            self._verify = jax.jit(make_spec_verify_step(model,
-                                                         max_seq=max_seq,
-                                                         **skw))
+            # a hybrid round keeps the pre-verify cache to roll recurrent
+            # rows back on a partial accept, so its verify cannot donate
+            self._verify = jax.jit(
+                make_spec_verify_step(model, max_seq=max_seq, **skw),
+                donate_argnums=() if model.cfg.family == "hybrid" else (1,))
         self.spec_rounds = 0
         self.spec_proposed = 0          # draft tokens offered to the verifier
         self.spec_accepted = 0          # draft tokens accepted
@@ -1254,8 +1274,10 @@ class DecodeScheduler:
             pos0 = slot.len
             if self._has_kv:
                 self._prepare_write_span(slot, pos0, C)
+            prev = _kv_leaf(self.cache)
             logits, self.cache = self._chunk(
                 self.params, self.cache, jnp.asarray(chunk)[None], slot.index)
+            self._count_donation(prev)
             slot.len += C
             slot.chunk_i += 1
             self.prefill_tokens += C
@@ -1281,6 +1303,17 @@ class DecodeScheduler:
                     slot.draft_len = 0
                     self.draft_cache["length"] = (
                         self.draft_cache["length"].at[slot.index].set(0))
+
+    def _count_donation(self, prev) -> None:
+        """Count a decode or chunk dispatch as donated when its input's KV
+        leaf is gone (JAX deletes a donated input at dispatch: a host
+        attribute read, no sync), else as copied."""
+        if prev is None:
+            return
+        if prev.is_deleted():
+            self.pool_donated_steps += 1
+        else:
+            self.pool_copied_steps += 1
 
     # -- decode loop ---------------------------------------------------------------
 
@@ -1470,10 +1503,12 @@ class DecodeScheduler:
                     mask = np.zeros((self.n_slots,), bool)
                     mask[active] = True
                     self._key, sub = jax.random.split(self._key)
+                    prev = _kv_leaf(self.cache)
                     self.cache, self.last_tokens, self.out_buf, self.out_pos = \
                         self._decode(
                             self.params, self.cache, self.last_tokens, self.out_buf,
                             self.out_pos, jnp.asarray(mask), sub)
+                    self._count_donation(prev)
                 self.decode_tokens += len(active)
                 for i in active:
                     st = self.slots[i]
@@ -1733,6 +1768,8 @@ class DecodeScheduler:
             "completed": self.completed,
             "kv_mode": self.kv_mode,
             "attn_backend": self.attn_backend,
+            "pool_donated_steps": self.pool_donated_steps,
+            "pool_copied_steps": self.pool_copied_steps,
         }
         if self.kv_mode == "paged":
             out["prefill_chunks"] = self.prefill_chunks

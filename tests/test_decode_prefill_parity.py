@@ -160,11 +160,11 @@ def test_parked_pages_are_prefill_bytes(arch):
     el = jax.tree_util.tree_leaves(exp)
     for (path, g), e in zip(gl, el):
         g, e = np.asarray(g), np.asarray(e)
-        # (..., n_pages, ps, H, D) -> (..., tokens, H, D); the tail of the
+        # (..., n_pages, ps, H*D) -> (..., tokens, H*D); the tail of the
         # last page is unwritten scratch, compared only up to `consumed`
-        g = g.reshape(g.shape[:-4] + (n_pages * ps,) + g.shape[-2:])
-        e = e.reshape(e.shape[:-4] + (n_pages * ps,) + e.shape[-2:])
-        sl = (Ellipsis, slice(0, rec.consumed), slice(None), slice(None))
+        g = g.reshape(g.shape[:-3] + (n_pages * ps,) + g.shape[-1:])
+        e = e.reshape(e.shape[:-3] + (n_pages * ps,) + e.shape[-1:])
+        sl = (Ellipsis, slice(0, rec.consumed), slice(None))
         assert g[sl].tobytes() == e[sl].tobytes(), \
             f"{arch}: parked pages differ from prefill bytes at " \
             f"{jax.tree_util.keystr(path)}"

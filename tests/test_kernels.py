@@ -132,8 +132,9 @@ def test_paged_attention_matches_model_gather_path():
     q, kp, vp, pt, lengths, k_new, v_new = paged_inputs(
         3, B, Hkv, G, D, ps, mp, 16, jnp.float32, holes=1)
     got = paged_attention(q, kp, vp, pt, lengths, k_new=k_new, v_new=v_new)
-    lc = {"kp": kp, "vp": vp, "page_table": pt}
-    ck, cv, kv_pos, kv_valid = kvcache.cache_kv_view(lc, upto=lengths)
+    lc = {"kp": kp.reshape(kp.shape[:2] + (-1,)),
+          "vp": vp.reshape(vp.shape[:2] + (-1,)), "page_table": pt}
+    ck, cv, kv_pos, kv_valid = kvcache.cache_kv_view(lc, upto=lengths, n_kv=Hkv)
     want = sdpa_append(q, ck, cv, k_new, v_new, window=None,
                        q_positions=kvcache.decode_positions(lengths, B, 1),
                        kv_positions=kv_pos, kv_valid=kv_valid)

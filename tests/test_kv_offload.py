@@ -98,9 +98,9 @@ def _round_trip(n_pages, ps, H, D, table_rows, seed):
     rng = np.random.default_rng(seed)
     L = 2
     pool = {
-        "kp": jnp.asarray(rng.standard_normal((L, n_pages, ps, H, D)),
+        "kp": jnp.asarray(rng.standard_normal((L, n_pages, ps, H * D)),
                           jnp.float32),
-        "vp": jnp.asarray(rng.standard_normal((L, n_pages, ps, H, D)),
+        "vp": jnp.asarray(rng.standard_normal((L, n_pages, ps, H * D)),
                           jnp.float32),
         "page_table": jnp.asarray(table_rows, jnp.int32)[None].repeat(L, 0),
         "length": jnp.zeros((len(table_rows),), jnp.int32),
@@ -111,7 +111,7 @@ def _round_trip(n_pages, ps, H, D, table_rows, seed):
             continue
         blob = kvcache.gather_pages(pool, ids)
         assert set(blob) == {"kp", "vp"}
-        assert blob["kp"].shape == (L, len(ids), ps, H, D)
+        assert blob["kp"].shape == (L, len(ids), ps, H * D)
         # inject into a cold pool at *different* physical pages (restore
         # never gets the same pages back) and extract again
         new_ids = [(p + 1) % n_pages for p in ids]
@@ -138,8 +138,8 @@ def test_gather_scatter_round_trip_scrambled():
 
 def test_scatter_leaves_other_pages_untouched():
     rng = np.random.default_rng(1)
-    pool = {"kp": jnp.asarray(rng.standard_normal((4, 2, 2, 2)), jnp.float32),
-            "vp": jnp.asarray(rng.standard_normal((4, 2, 2, 2)), jnp.float32),
+    pool = {"kp": jnp.asarray(rng.standard_normal((4, 2, 4)), jnp.float32),
+            "vp": jnp.asarray(rng.standard_normal((4, 2, 4)), jnp.float32),
             "page_table": jnp.zeros((1, 2), jnp.int32)}
     blob = kvcache.gather_pages(pool, [3])
     out = kvcache.scatter_pages(pool, [0], blob)
@@ -149,7 +149,7 @@ def test_scatter_leaves_other_pages_untouched():
                                   np.asarray(pool["kp"][3]))
     # slicing a blob is slicing its page axis
     piece = kvcache.slice_page_blob(blob, 0, 1)
-    assert piece["kp"].shape == (1, 2, 2, 2)
+    assert piece["kp"].shape == (1, 2, 4)
 
 
 try:  # optional dep, guarded like test_kernel_properties (skip, not error)
@@ -398,18 +398,19 @@ def test_offload_stage_shardings_resolve():
     # model=16, so the within-page lane dim rides the model axis and nothing
     # else does (page dim replicated even though it would divide)
     assert specs is not None and "kp" in specs
-    assert specs["kp"][-3] == "model"
-    assert all(e is None for i, e in enumerate(specs["kp"]) if i != len(specs["kp"]) - 3)
-    # when the lane doesn't divide, heads are the fallback — exactly the
-    # pool's own fallback order, so scatter/gather stay shard-local
+    assert specs["kp"][-2] == "model"
+    assert all(e is None for i, e in enumerate(specs["kp"]) if i != len(specs["kp"]) - 2)
+    # when the lane doesn't divide, heads x head_dim are the fallback —
+    # exactly the pool's own fallback order, so scatter/gather stay
+    # shard-local
     mesh2 = AbstractMesh((2, 2), ("data", "model"))
-    stage2 = {"kp": jax.ShapeDtypeStruct((3, 5, 4, 8), jnp.bfloat16)}
+    stage2 = {"kp": jax.ShapeDtypeStruct((3, 5, 32), jnp.bfloat16)}
     specs2 = jax.tree_util.tree_map(
         lambda s: s.spec, offload_stage_shardings(stage2, mesh2))
-    assert specs2["kp"][-2] == "model"
+    assert specs2["kp"][-1] == "model"
     assert all(e is None for i, e in enumerate(specs2["kp"]) if i != 2)
     # neither divides -> fully replicated (never the page dim)
-    stage3 = {"kp": jax.ShapeDtypeStruct((4, 5, 3, 8), jnp.bfloat16)}
+    stage3 = {"kp": jax.ShapeDtypeStruct((4, 5, 3), jnp.bfloat16)}
     specs3 = jax.tree_util.tree_map(
         lambda s: s.spec, offload_stage_shardings(stage3, mesh2))
     assert all(e is None for e in specs3["kp"])

@@ -130,10 +130,15 @@ def test_paged_kernel_parity_over_shared_cow_prefix():
 
 
 def _scrambled_layer_cache(rng, *, n_pages, ps, Hkv, D, table):
-    shape = (n_pages, ps, Hkv, D)
+    shape = (n_pages, ps, Hkv * D)
     return {"kp": jnp.asarray(rng.standard_normal(shape), jnp.float32),
             "vp": jnp.asarray(rng.standard_normal(shape), jnp.float32),
             "page_table": jnp.asarray(table, jnp.int32)}
+
+
+def _pages(pool, D):
+    """A pool leaf as the kernel's oracle reads it: (n_pages, ps, Hkv, D)."""
+    return pool.reshape(pool.shape[:-1] + (-1, D))
 
 
 def test_paged_attn_decode_scrambled_and_holey_table():
@@ -157,7 +162,7 @@ def test_paged_attn_decode_scrambled_and_holey_table():
         rkw = (dict(k_new=k_new, v_new=v_new) if "k_new" in hkw
                else dict(q_pos=pos))
         lengths = pos if "k_new" in hkw else pos + 1
-        ref = reference_paged_attention(q, lc["kp"], lc["vp"],
+        ref = reference_paged_attention(q, _pages(lc["kp"], D), _pages(lc["vp"], D),
                                         lc["page_table"], lengths, **rkw)
         assert out.shape == q.shape and out.dtype == q.dtype
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -168,8 +173,9 @@ def test_paged_attn_decode_scrambled_and_holey_table():
     # sliding window through the helper trims the same lanes as the oracle
     out = kvcache.paged_attn_decode(lc, q, pos, window=5, k_new=k_new,
                                     v_new=v_new)
-    ref = reference_paged_attention(q, lc["kp"], lc["vp"], lc["page_table"],
-                                    pos, window=5, k_new=k_new, v_new=v_new)
+    ref = reference_paged_attention(q, _pages(lc["kp"], D), _pages(lc["vp"], D),
+                                    lc["page_table"], pos, window=5,
+                                    k_new=k_new, v_new=v_new)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-6, rtol=2e-6)
 

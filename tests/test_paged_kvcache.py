@@ -110,7 +110,7 @@ def test_paged_update_view_matches_ring_lanes():
             "positions": -jnp.ones((B, T), jnp.int32)}
     # physical pages deliberately scrambled: logical order must not care
     table = jnp.asarray([[5, 2, 7, 0], [1, 6, 3, 4]], jnp.int32)
-    paged = {"kp": jnp.zeros((9, ps, H, D)), "vp": jnp.zeros((9, ps, H, D)),
+    paged = {"kp": jnp.zeros((9, ps, H * D)), "vp": jnp.zeros((9, ps, H * D)),
              "page_table": table}
     assert kvcache.cache_capacity(paged) == T
 
@@ -130,7 +130,7 @@ def test_paged_update_view_matches_ring_lanes():
 
     upto = jnp.asarray([8, 5], jnp.int32)
     rk, rv, rpos, rvalid = kvcache.cache_kv_view(ring)
-    pk, pv, ppos, pvalid = kvcache.cache_kv_view(paged, upto=upto)
+    pk, pv, ppos, pvalid = kvcache.cache_kv_view(paged, upto=upto, n_kv=H)
     w = np.asarray(rvalid)
     np.testing.assert_array_equal(np.asarray(pvalid)[:, : T], w)
     np.testing.assert_array_equal(np.asarray(pk)[w], np.asarray(rk)[w])
@@ -342,7 +342,7 @@ def test_paged_cache_shardings_resolve_on_16x16():
     sched = DecodeScheduler(model, params, n_slots=16, max_seq=32,
                             kv_mode="paged", page_size=16, mesh=mesh)
     specs = sched.cache_specs
-    # pool (L, Np, ps, H, D): shared across slots -> replicated over data;
+    # pool (L, Np, ps, H*D): shared across slots -> replicated over data;
     # the reduced config's 4 kv heads don't divide model=16, so the guard
     # falls back to the within-page lane dim (never the page dim — the
     # kernel's table-indirect page slices would all-gather the pool)

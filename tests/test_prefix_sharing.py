@@ -135,8 +135,8 @@ def test_prefix_index_publish_lookup_evict():
 
 def test_copy_pages_lane_exact():
     rng = np.random.default_rng(3)
-    pool = {"kp": jnp.asarray(rng.standard_normal((2, 5, 4, 2, 3)), jnp.float32),
-            "vp": jnp.asarray(rng.standard_normal((2, 5, 4, 2, 3)), jnp.float32),
+    pool = {"kp": jnp.asarray(rng.standard_normal((2, 5, 4, 6)), jnp.float32),
+            "vp": jnp.asarray(rng.standard_normal((2, 5, 4, 6)), jnp.float32),
             "page_table": jnp.zeros((2, 1, 2), jnp.int32)}
     out = kvcache.copy_pages(pool, [1, 3], [0, 4])
     for k in ("kp", "vp"):
@@ -603,6 +603,6 @@ def test_shared_pool_specs_survive_sharing():
     specs = sched.cache_specs
     assert specs is not None
     kp = specs["layers"]["kp"] if "layers" in specs else specs["kp"]
-    assert kp[-3] == "model"
-    assert all(e is None for e in kp[:-3] + kp[-2:])
+    assert kp[-2] == "model"
+    assert all(e is None for e in kp[:-2] + kp[-1:])
     assert sched.stage_specs is not None

@@ -141,3 +141,39 @@ def test_minicpm_serving_step_fits_one_v5e(one_chip, minicpm, monkeypatch,
     _fits(compiled)
     assert ("tpu_custom_call" in compiled.as_text()) == (
         backend == "paged_kernel")
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_minicpm_scheduler_steps_alias_pool_on_v5e(one_chip, minicpm, step):
+    """The scheduler's own jitted decode and chunk steps, compiled for the
+    chip at chip_smoke's pool size, donate the cache: every pool byte they
+    are given is aliased to the cache they return, and they fit HBM."""
+    from repro.serve.scheduler import DecodeScheduler
+
+    model = build_model(minicpm)
+    params = _on(one_chip, jax.eval_shape(
+        lambda k: to_compute_dtype(model.init(k)), jax.random.key(0)))
+    # the steps depend on the shapes they are lowered with, not on the
+    # scheduler's own pool, which is the smallest it accepts
+    sched = DecodeScheduler(model, params, n_slots=SLOTS,
+                            max_seq=MAX_PAGES * PAGE, page_size=PAGE,
+                            kv_pages=MAX_PAGES, prefill_chunk=CHUNK)
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: kvcache.paged_cache(model, SLOTS, page_size=PAGE,
+                                    n_pages=N_PAGES, max_pages=MAX_PAGES)))
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    if step == "decode":
+        lowered = sched._decode.lower(
+            params, cache, S((SLOTS,), jnp.int32),
+            S((SLOTS, sched.max_seq), jnp.int32), S((SLOTS,), jnp.int32),
+            S((SLOTS,), jnp.bool_),
+            jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                                 sharding=one_chip))
+    else:
+        lowered = sched._chunk.lower(params, cache, S((1, CHUNK), jnp.int32),
+                                     S((), jnp.int32))
+    compiled = lowered.compile()
+    pool = sum(leaf.size * leaf.dtype.itemsize for key, leaf in cache.items()
+               if key in kvcache.POOL_KEYS)
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool
+    _fits(compiled)
