@@ -3,16 +3,20 @@
 A cell is one entry of ``workloads`` in ``BENCHMARK.json``: a configuration
 (``configs/<config>.json``) under a traffic mix (``traffic/<traffic>.json``).
 Its metrics are the ``end_to_end`` and ``per_layer`` entries that apply to
-it, each read by ``metrics/<name>.py``.  Adding a configuration, a traffic
-mix or a metric is adding its file and its entry; nothing here changes.
+it, each read by ``metrics/<name>.py``.  A configuration names its block
+(``blocks/<block>.py``, ``dense`` by default) and, on several chips, its
+mesh (``serving.mesh``).  Adding a configuration, a block, a traffic mix or
+a metric is adding its file and its entry; nothing here changes.
 
-Only :func:`program_config` and :func:`build_scheduler` touch the program,
-through ``repro.configs`` and ``repro.serve.scheduler.DecodeScheduler``.
+Only :func:`program_config`, :func:`mesh` and :func:`build_scheduler` touch
+the program, through ``repro.configs``, ``repro.launch.mesh.make_mesh`` and
+``repro.serve.scheduler.DecodeScheduler``.
 """
 
 from __future__ import annotations
 
 import json
+import math
 from pathlib import Path
 from typing import Dict, List
 
@@ -69,24 +73,34 @@ def traffic(name: str) -> Dict:
     return load_json(HERE / "traffic" / f"{name}.json")
 
 
+def block(cfg: Dict):
+    """The configuration's block file, ``blocks/<block>.py``."""
+    from . import blocks
+
+    name = cfg.get("block", "dense")
+    try:
+        return blocks.load(name)
+    except FileNotFoundError as e:
+        raise CellError(f"{cfg.get('name')}: {e}") from e
+
+
 def dims(cfg: Dict) -> Dict:
     """The sizes the yardstick needs, from a configuration's published keys
-    (Hugging Face names)."""
+    (Hugging Face names): those every block shares, and its block's own."""
     d = cfg["hidden_size"]
     H = cfg["num_attention_heads"]
     hd = cfg.get("head_dim", d // H)
     norm = "layernorm" if cfg.get("norm_type") == "layer_norm" else "rmsnorm"
     eps = cfg.get("layer_norm_epsilon", cfg.get("rms_norm_eps"))
-    act = cfg["hidden_act"]
-    gated = act == "silu"
     L = cfg["num_hidden_layers"]
+    blk = block(cfg)
     return {
+        "block": cfg.get("block", "dense"),
         "L": L, "d": d, "H": H, "Hkv": cfg["num_key_value_heads"], "hd": hd,
-        "ff": cfg["intermediate_size"], "vocab": cfg["vocab_size"],
+        "vocab": cfg["vocab_size"],
         "vocab_pad": -(-cfg["vocab_size"] // cfg["program"]["vocab_pad"])
         * cfg["program"]["vocab_pad"],
-        "norm": norm, "eps": eps, "gated": gated, "act": act,
-        "bias": bool(cfg.get("use_bias", False)),
+        "norm": norm, "eps": eps,
         "tied": bool(cfg.get("tie_word_embeddings", False)),
         "theta": cfg["rope_theta"],
         "window": cfg.get("sliding_window"),
@@ -95,21 +109,22 @@ def dims(cfg: Dict) -> Dict:
                            if "scale_depth" in cfg else 1.0),
         "logit_scale": (cfg["dim_model_base"] / d
                         if "dim_model_base" in cfg else 1.0),
+        **blk.dims(cfg),
     }
 
 
 # published key -> the program's ArchConfig attribute
 _PROGRAM_FIELDS = {
     "L": "n_layers", "d": "d_model", "H": "n_heads", "Hkv": "n_kv_heads",
-    "ff": "d_ff", "vocab": "vocab", "theta": "rope_theta",
-    "window": "sliding_window",
+    "vocab": "vocab", "theta": "rope_theta", "window": "sliding_window",
+    "norm": "norm",
 }
 
 
 def program_config(cfg: Dict):
     """The program's config for this configuration, checked against every
-    published size the benchmark relies on: a cell runs at published widths
-    or not at all."""
+    published size the benchmark relies on, its block's among them: a cell
+    runs at published widths or not at all."""
     from repro import configs
 
     arch = configs.get(cfg["program"]["arch"])
@@ -125,21 +140,43 @@ def program_config(cfg: Dict):
     for k, v in scales.items():
         if abs(dm[k] - v) > 1e-9 * max(1.0, abs(v)):
             bad[k] = (dm[k], v)
-    if (arch.mlp == "swiglu") != dm["gated"] or arch.norm != dm["norm"]:
-        bad["block"] = ((dm["gated"], dm["norm"]), (arch.mlp, arch.norm))
+    bad.update(block(cfg).check_program(arch, dm))
     if bad:
         raise CellError(f"{cfg['name']}: the program's {arch.name} differs "
                         f"from the configuration (want, have): {bad}")
     return arch
 
 
-def build_scheduler(model, params, cfg: Dict):
+def mesh(cfg: Dict, devices, chips: int):
+    """The cell's mesh over ``devices[:chips]``, named by the configuration's
+    ``serving.mesh`` (axis name to size, e.g. ``{"data": 1, "model": 4}``);
+    ``None`` for a configuration without one, which runs on one chip."""
+    shape = cfg["serving"].get("mesh")
+    if shape is None:
+        if chips != 1:
+            raise CellError(f"{cfg['name']}: a cell on {chips} chips needs "
+                            "serving.mesh in its configuration")
+        return None
+    size = math.prod(shape.values())
+    if size != chips:
+        raise CellError(f"{cfg['name']}: serving.mesh {shape} holds {size} "
+                        f"chips, the cell asks for {chips}")
+    if len(devices) < chips:
+        raise CellError(f"{cfg['name']}: {chips} chips asked for, "
+                        f"{len(devices)} given")
+    from repro.launch.mesh import make_mesh
+
+    return make_mesh(tuple(shape.values()), tuple(shape), devices=devices[:chips])
+
+
+def build_scheduler(model, params, cfg: Dict, mesh=None):
     """The timed entry, built with the serving defaults of
-    ``repro.launch.serve``: only the sizes a cell needs are set."""
+    ``repro.launch.serve``: only the sizes a cell needs, and its mesh, are
+    set."""
     from repro.serve.scheduler import DecodeScheduler
 
     s = cfg["serving"]
     return DecodeScheduler(model, params, n_slots=s["slots"],
                            max_seq=s["max_seq"], page_size=s["page_size"],
                            kv_pages=s["kv_pages"],
-                           prefill_chunk=s["prefill_chunk"])
+                           prefill_chunk=s["prefill_chunk"], mesh=mesh)

@@ -3,9 +3,9 @@
 Once the window has closed and the program's state is freed, a sample of the
 finished requests (the one with the longest output, then others drawn from
 the seed) goes through the float32 reference once, over each prompt with its
-served tokens.  At every served position the number read is how far the
-served token's reference logit lies below the reference's best logit; the
-run's numbers are the widest such gap and their mean over the served
+served tokens, on the cell's chips.  At every served position the number
+read is how far the served token's reference logit lies below the
+reference's best logit; the run's numbers are the widest such gap and their mean over the served
 tokens (a configuration's ``correctness.limits`` says which are compared,
 each with its limit: :func:`judge`).  A greedy server computing the
 configuration's arithmetic picks the reference's best token up to rounding,
@@ -57,13 +57,18 @@ def pack(reqs: List, k: int, t_ref: int, n_ref: int) -> Dict[str, np.ndarray]:
 
 
 def gaps(dm: Dict, seed: int, reqs: List, *, k: int, t_ref: int, n_ref: int,
-         program_head: bool, control: bool = False) -> Dict[str, float]:
+         program_head: bool, devices, control: bool = False) -> Dict[str, float]:
     """Widest and mean logit gap of the served tokens and, with ``control``,
-    of the fp8 reference's first choices (keys ``control_...``)."""
+    of the fp8 reference's first choices (keys ``control_...``).  The
+    reference runs over the cell's ``devices``, its weights spread over them
+    (``weights.spread``)."""
     import jax.numpy as jnp
 
     b = pack(reqs, k, t_ref, n_ref)
-    params = weights.make(dm, program_head=program_head)(*weights.split_seed(seed))
+    params = weights.make(
+        dm, program_head=program_head,
+        shardings=weights.spread(dm, devices, program_head=program_head),
+    )(*weights.split_seed(seed))
     tok, pos = jnp.asarray(b["tokens"]), jnp.asarray(b["pos"])
     targets = [b["served"]]
     if control:

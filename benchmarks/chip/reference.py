@@ -1,31 +1,27 @@
-"""The plain float32 reference of the benchmark's dense decoders.
+"""The plain float32 reference of the benchmark's decoders.
 
-Written from the published descriptions (Hugging Face ``modeling_minicpm``
-and ``modeling_starcoder2``), independent of the program: it imports nothing
-of ``repro`` and reads its weights from :mod:`weights`, made again from the
-seed.  A full causal forward over each sequence, no cache, no batching of
-decode steps, every matrix product at ``Precision.HIGHEST`` in float32.
+Independent of the program: it imports nothing of ``repro`` and reads its
+weights from :mod:`weights`, made again from the seed.  A full causal
+forward over each sequence, no cache, no batching of decode steps, every
+matrix product at ``Precision.HIGHEST`` in float32.  What every
+configuration shares is here:
 
 - embedding times ``scale_emb``; residual branches times
-  ``scale_depth / sqrt(layers)`` (MiniCPM);
+  ``scale_depth / sqrt(layers)`` (MiniCPM; the block applies it);
 - RMSNorm or LayerNorm with the published eps;
 - rotary embedding on the two halves of each head (``rotate_half``);
 - grouped-query causal attention, with the sliding window where the
   configuration has one, in blocks of query rows so that the score matrix
   of a long sequence fits;
-- SwiGLU (``silu``) or the biased tanh-GELU MLP;
+- the scan over the layers, each the configuration's block
+  (``blocks/<name>.py``, its ``block``), and the final norm;
 - the head (the transposed embedding when tied) times
   ``dim_model_base / hidden_size`` (MiniCPM).
-
-StarCoder2's attention output bias is part of the published block; the
-benchmark's weights set it to zero because the program has none, so it adds
-nothing here.
 
 ``quant="fp8"`` is the correctness control: every matrix product (the
 projections, the attention scores and values, the MLP and the head) takes
 its operands through float8 e4m3, scaled per row or column of the
-contracted dimension, and accumulates in float32.
-"""
+contracted dimension, and accumulates in float32."""
 
 from __future__ import annotations
 
@@ -35,6 +31,8 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+
+from . import blocks
 
 HI = jax.lax.Precision.HIGHEST
 Q_BLOCK = 512          # query rows per attention block
@@ -48,14 +46,14 @@ def _fp8(x, axis):
     return (x / s).astype(F8).astype(jnp.float32) * s
 
 
-def _mm(x, w, quant: Optional[str]):
+def mm(x, w, quant: Optional[str]):
     """``x (..., k) @ w (k, n)`` in float32, or through fp8 for the control."""
     if quant == "fp8":
         x, w = _fp8(x, -1), _fp8(w, 0)
     return jnp.einsum("...k,kn->...n", x, w, precision=HI)
 
 
-def _norm(x, p, dm):
+def norm(x, p, dm):
     if dm["norm"] == "layernorm":
         mu = x.mean(-1, keepdims=True)
         var = ((x - mu) ** 2).mean(-1, keepdims=True)
@@ -64,7 +62,7 @@ def _norm(x, p, dm):
     return x * jax.lax.rsqrt(ms + dm["eps"]) * p["scale"]
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """x (T, H, D): rotate the halves ``x1, x2`` of every head."""
     T, _, D = x.shape
     inv = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
@@ -74,7 +72,7 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _attention(q, k, v, dm, quant):
+def attention(q, k, v, dm, quant):
     """One sequence: q (T, H, D), k/v (T, Hkv, D) -> (T, H, D)."""
     T, H, D = q.shape
     G = H // dm["Hkv"]
@@ -104,37 +102,6 @@ def _attention(q, k, v, dm, quant):
     return out.reshape(nb * Q_BLOCK, H, D)[:T]
 
 
-def _block(x, lp, dm, quant):
-    K, T, d = x.shape
-    H, Hkv, D = dm["H"], dm["Hkv"], dm["hd"]
-    a = lp["attn"]
-    h = _norm(x, lp["attn_norm"], dm)
-    q = _mm(h, a["wq"], quant)
-    k = _mm(h, a["wk"], quant)
-    v = _mm(h, a["wv"], quant)
-    if dm["bias"]:
-        q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
-    q = q.reshape(K, T, H, D)
-    k = k.reshape(K, T, Hkv, D)
-    v = v.reshape(K, T, Hkv, D)
-
-    def one(args):
-        qq, kk, vv = args
-        return _attention(_rope(qq, dm["theta"]), _rope(kk, dm["theta"]), vv, dm, quant)
-
-    o = jax.lax.map(one, (q, k, v)).reshape(K, T, H * D)
-    x = x + _mm(o, a["wo"], quant) * dm["residual_scale"]
-    h = _norm(x, lp["mlp_norm"], dm)
-    m = lp["mlp"]
-    if dm["gated"]:
-        u = jax.nn.silu(_mm(h, m["w_gate"], quant)) * _mm(h, m["w_up"], quant)
-        y = _mm(u, m["w_down"], quant)
-    else:
-        u = jax.nn.gelu(_mm(h, m["w_up"], quant) + m["b_up"], approximate=True)
-        y = _mm(u, m["w_down"], quant) + m["b_down"]
-    return x + y * dm["residual_scale"]
-
-
 def _f32(tree):
     return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), tree)
 
@@ -144,17 +111,19 @@ def _forward(params, tokens, pos, targets, *, dm_items, quant):
     dm = dict(dm_items)
     x = params["embedding"]["embed"][tokens].astype(jnp.float32) * dm["emb_scale"]
 
+    block = blocks.load(dm["block"]).block
+
     def layer(x, lp):
-        return _block(x, _f32(lp), dm, quant), None
+        return block(x, _f32(lp), dm, quant), None
 
     x, _ = jax.lax.scan(layer, x, params["layers"])
-    x = _norm(x, _f32(params["final_norm"]), dm)
+    x = norm(x, _f32(params["final_norm"]), dm)
     xg = jnp.take_along_axis(x, pos[..., None], axis=1)             # (K, N, d)
     if dm["tied"]:
         head = params["embedding"]["embed"][: dm["vocab"]].T
     else:
         head = params["embedding"]["head"][:, : dm["vocab"]]
-    logits = _mm(xg, head.astype(jnp.float32), quant) * dm["logit_scale"]
+    logits = mm(xg, head.astype(jnp.float32), quant) * dm["logit_scale"]
     best = logits.max(-1)
     at = jnp.take_along_axis(logits[None], targets[..., None], axis=-1)[..., 0]
     return best, at, logits.argmax(-1).astype(jnp.int32)

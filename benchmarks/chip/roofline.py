@@ -1,12 +1,13 @@
-"""What a decode step has to do, counted from shapes, and the chip's peaks.
+"""What a decode step has to do, counted from shapes, and the peaks of the
+cell's chips.
 
 The counts are of the work the algorithm needs, not of what the current code
 does, so a change of kernel or fusion leaves the yardstick as it is:
 
-- bytes: every weight a decode step reads once (all blocks, the final norm
-  and the head; the embedding only for the rows of the new tokens), the K/V
-  of the valid positions of the active slots, and the K/V written for each
-  new token;
+- bytes: every weight a decode step reads once (all blocks, each counted by
+  its ``blocks/<name>.py``; the final norm and the head; the embedding only
+  for the rows of the new tokens), the K/V of the valid positions of the
+  active slots, and the K/V written for each new token;
 - FLOPs per new token: 2 x the blocks' matrix parameters, 4 x layers x heads
   x head_dim x context for attention (scores and values), and 2 x d_model x
   vocab for the head.
@@ -18,31 +19,30 @@ import json
 from pathlib import Path
 from typing import Dict
 
+from . import blocks
+
 BF16 = 2
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 
 
-def peaks(device_kind: str) -> Dict[str, float]:
-    """The chip's published peaks; an unknown kind is an error."""
+def peaks(device_kind: str, chips: int = 1) -> Dict[str, float]:
+    """The published peaks of ``chips`` chips of a kind together: one
+    chip's FLOP/s and bandwidth times the count, so a mesh's work reads
+    against the whole mesh.  An unknown kind is an error."""
     table = json.loads(PEAKS.read_text())
     if device_kind not in table or device_kind == "source":
         raise KeyError(f"no peaks for device kind {device_kind!r} in {PEAKS.name}")
-    return table[device_kind]
+    one = table[device_kind]
+    return dict(one, bf16_flops_per_s=chips * one["bf16_flops_per_s"],
+                hbm_bytes_per_s=chips * one["hbm_bytes_per_s"])
 
 
 def block_matrix_params(dm: Dict) -> int:
-    d, hd = dm["d"], dm["hd"]
-    q, kv = dm["H"] * hd, dm["Hkv"] * hd
-    attn = d * (q + 2 * kv) + q * d
-    mlp = d * dm["ff"] * (3 if dm["gated"] else 2)
-    return dm["L"] * (attn + mlp)
+    return dm["L"] * blocks.load(dm["block"]).matrix_params(dm)
 
 
 def block_vector_params(dm: Dict) -> int:
-    d, hd = dm["d"], dm["hd"]
-    norms = 2 * d * (2 if dm["norm"] == "layernorm" else 1)
-    bias = (dm["H"] * hd + 2 * dm["Hkv"] * hd + dm["ff"] + d) if dm["bias"] else 0
-    return dm["L"] * (norms + bias)
+    return dm["L"] * blocks.load(dm["block"]).vector_params(dm)
 
 
 def kv_bytes_per_token(dm: Dict) -> int:

@@ -4,8 +4,10 @@
         --seconds <s> --trace <0|1>
 
 A cell of ``BENCHMARK.json`` names a configuration and a traffic mix.  The
-run makes the configuration's weights on the chip from ``--seed``, builds
-``DecodeScheduler`` with the serving defaults of ``repro.launch.serve``,
+run makes the configuration's weights on the cell's chips from ``--seed``
+(on several chips in the program's placement over the configuration's
+``serving.mesh``), builds ``DecodeScheduler`` on that mesh with the serving
+defaults of ``repro.launch.serve``,
 warms every shape the mix uses, then plays the mix against the scheduler on
 the host's clock for ``--seconds``: requests are submitted when due, the
 harness calls ``step()``, waits for the step's state and timestamps every
@@ -177,15 +179,15 @@ def warm_up(sched, spec: Dict, *, seconds: float, chunk: int, vocab: int,
     steps = 0
     while sched.busy():
         sched.step()
-        # one step in flight at a time, as in the window: no step donates
-        # its cache, so each queued step would hold a pool of its own
+        # one step in flight at a time, as in the window, where the harness
+        # waits for each step's state before it reads the step's tokens
         jax.block_until_ready(sched.cache)
         steps += 1
     return steps
 
 
 def serve(sched, spec: Dict, reqs: List[traffic.Request], *, seconds: float,
-          traced: bool, counter: CompileCounter) -> record.Run:
+          traced: bool, counter: CompileCounter, devices) -> record.Run:
     """The measured window (and the drain after it)."""
     import jax
 
@@ -238,7 +240,7 @@ def serve(sched, spec: Dict, reqs: List[traffic.Request], *, seconds: float,
         h.step()
     tr = None
     if traced:
-        tr = tracing.load(logdir)
+        tr = tracing.load(logdir, [d.id for d in devices])
         shutil.rmtree(logdir, ignore_errors=True)
     run = record.Run(t0=t0, t1=t1, setup_s=t0 - T_START, requests=h.reqs,
                      window_requests=window_reqs, steps=h.steps, dm={},
@@ -251,8 +253,8 @@ def run_cell(workload: Dict, *, seed: int, seconds: float, traced: bool,
              devices, cfg: Optional[Dict] = None, spec: Optional[Dict] = None,
              rate_per_s: Optional[float] = None, control: bool = False,
              sabotage: Optional[Callable] = None):
-    """One run of a cell; returns the result line's object and the
-    :class:`record.Run` it was read from.  ``cfg`` and
+    """One run of a cell on ``devices[:chips]``; returns the result line's
+    object and the :class:`record.Run` it was read from.  ``cfg`` and
     ``spec`` override the cell's files (tests), ``rate_per_s`` the mix's
     rate (the knee sweep), ``control`` puts the control in the program's
     place for ``correct`` (the program's readings stay in ``run.gaps``), and
@@ -268,10 +270,17 @@ def run_cell(workload: Dict, *, seed: int, seconds: float, traced: bool,
     spec = spec or cell.traffic(workload["traffic"])
     dm = cell.dims(cfg)
     arch = cell.program_config(cfg)
+    mesh = cell.mesh(cfg, devices, workload["chips"])
+    devices = devices[: workload["chips"]]
     model = build_model(arch)
     program_head = not arch.tie_embeddings
-    make = weights.make(dm, program_head=program_head)
     want = jax.eval_shape(lambda k: to_compute_dtype(model.init(k)), jax.random.key(0))
+    shardings = None
+    if mesh is not None:
+        from repro.dist.sharding import param_shardings
+
+        shardings = param_shardings(want, mesh)
+    make = weights.make(dm, program_head=program_head, shardings=shardings)
     have = jax.eval_shape(make, *weights.split_seed(0))
     if jax.tree_util.tree_structure(want) != jax.tree_util.tree_structure(have) or any(
             (a.shape, a.dtype) != (b.shape, b.dtype) for a, b in
@@ -282,7 +291,18 @@ def run_cell(workload: Dict, *, seed: int, seconds: float, traced: bool,
     counter = CompileCounter()
     params = jax.block_until_ready(make(*weights.split_seed(seed)))
     log(f"weights made at {time.perf_counter() - T_START:.2f} s")
-    sched = cell.build_scheduler(model, params, cfg)
+    sched = cell.build_scheduler(model, params, cfg, mesh)
+    if mesh is not None:
+        # the scheduler places the params itself; made in its placement,
+        # they must stay where they are, or each chip would briefly hold
+        # two copies of its share
+        moved = [jax.tree_util.keystr(p) for (p, a), b in zip(
+            jax.tree_util.tree_leaves_with_path(params),
+            jax.tree_util.tree_leaves(sched.params), strict=True)
+            if a.sharding != b.sharding]
+        if moved:
+            raise cell.CellError(f"the scheduler moved {len(moved)} weight leaves "
+                                 f"from where they were made: {moved[:4]}")
     if sabotage is not None:
         sabotage(sched)
     reqs = traffic.generate(spec, seed=seed, seconds=seconds, vocab=dm["vocab"],
@@ -290,7 +310,8 @@ def run_cell(workload: Dict, *, seed: int, seconds: float, traced: bool,
     n_warm = warm_up(sched, spec, seconds=seconds, chunk=cfg["serving"]["prefill_chunk"],
                      vocab=dm["vocab"], seed=seed)
     log(f"warm-up: {n_warm} steps, done at {time.perf_counter() - T_START:.2f} s")
-    run = serve(sched, spec, reqs, seconds=seconds, traced=traced, counter=counter)
+    run = serve(sched, spec, reqs, seconds=seconds, traced=traced, counter=counter,
+                devices=devices)
     run.dm = dm
     kind = devices[0].device_kind
     mem = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devices)
@@ -309,7 +330,7 @@ def run_cell(workload: Dict, *, seed: int, seconds: float, traced: bool,
     k = spec["check_requests"]
     g = check.gaps(dm, seed, picked, k=k, t_ref=cfg["serving"]["max_seq"],
                    n_ref=traffic.shapes(spec, seconds=seconds, chunk=1)["output_max"],
-                   program_head=program_head, control=control)
+                   program_head=program_head, control=control, devices=devices)
     bad = sum(1 for r in finished
               if len(r.tokens) != r.max_new
               or not all(0 <= t < dm["vocab"] for t in r.tokens))
@@ -322,7 +343,8 @@ def run_cell(workload: Dict, *, seed: int, seconds: float, traced: bool,
                                   prefix="control_" if control else "")
     run.gaps = g
 
-    run.peak = roofline.peaks(kind) if devices[0].platform == "tpu" else None
+    run.peak = (roofline.peaks(kind, len(devices)) if devices[0].platform == "tpu"
+                else None)
     names = cell.metrics_for(cell.benchmark(), workload["name"], traced=traced)
     metrics = {}
     for m in names:

@@ -5,7 +5,7 @@ dict (device ops and programs per chip, the harness's host spans, the traced
 window), and the rest reduces that dict.  Tests check the reduction on a
 small trace recorded on the chip, kept as that dict.
 
-On a TPU the profiler gives one plane per chip, ``/device:TPU:<n>``, with a
+On a TPU the profiler gives one plane per chip, ``/device:TPU:<id>``, with a
 line ``XLA Ops`` (one event per operation) and a line ``XLA Modules`` (one
 event per call of a compiled program, named after the jitted function).
 The harness's spans (``jax.profiler.TraceAnnotation``) sit on the host
@@ -18,6 +18,7 @@ import glob
 import os
 from typing import Dict, List, Optional, Tuple
 
+TPU_PLANE = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 SPANS = ("window", "submit", "step", "token_read", "generator")
@@ -36,10 +37,11 @@ def options():
     return o
 
 
-def load(logdir: str) -> Dict:
+def load(logdir: str, device_ids: Optional[List[int]] = None) -> Dict:
     """``{"devices": {plane: {"ops": [...], "modules": [...]}}, "spans":
     [...], "window": [start, end]}``, times in seconds on the profiler's
-    clock."""
+    clock.  ``device_ids``: the chips the cell ran on; the planes of other
+    chips of the host are left out."""
     from jax.profiler import ProfileData
 
     paths = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"), recursive=True)
@@ -49,7 +51,9 @@ def load(logdir: str) -> Dict:
     devices: Dict[str, Dict[str, List[Interval]]] = {}
     spans: List[Interval] = []
     for plane in pd.planes:
-        if plane.name.startswith("/device:TPU:"):
+        if plane.name.startswith(TPU_PLANE):
+            if device_ids is not None and _plane_id(plane.name) not in device_ids:
+                continue
             dev = devices.setdefault(plane.name, {"ops": [], "modules": []})
             for line in plane.lines:
                 key = {OPS_LINE: "ops", MODULES_LINE: "modules"}.get(line.name)
@@ -67,6 +71,10 @@ def load(logdir: str) -> Dict:
         raise ValueError("the trace holds no 'window' span")
     return {"devices": devices, "spans": [s for s in spans if s[0] != "window"],
             "window": [win[0][1], win[0][2]]}
+
+
+def _plane_id(name: str) -> int:
+    return int(name[len(TPU_PLANE):].split()[0])
 
 
 def union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:

@@ -2,8 +2,12 @@
 
 One jitted call makes every weight on the device, in bfloat16: the layers as
 a ``lax.map`` over the layer index, so each layer's float32 draw is a
-temporary of one layer.  The reference makes them again with the same call
-after the program's state is freed, so it takes nothing the program made.
+temporary of one layer.  A layer's leaves are its block's
+(``blocks/<name>.py``).  On a mesh the call makes each leaf in the
+program's placement, so no chip holds more than its share.  The reference
+makes them again with the same call, in a placement of its own
+(:func:`spread`), after the program's state is freed, so it takes nothing
+the program made.
 
 Each leaf draws from its own key, ``fold_in(k, crc32(path))``, where ``k``
 is the seed's key, or for a layer's leaf ``fold_in(seed key, 1 + layer)``.
@@ -29,34 +33,17 @@ from typing import Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
-Leaf = Tuple[Tuple[str, ...], Tuple[int, ...], str]
+from . import blocks
+
+# (path, shape, kind), and the dtype where the program serves the leaf in
+# another than bfloat16
+Leaf = Tuple
 
 
-def _norm_leaves(prefix: Tuple[str, ...], d: int, dm: Dict) -> List[Leaf]:
+def norm_leaves(prefix: Tuple[str, ...], d: int, dm: Dict) -> List[Leaf]:
     out = [((*prefix, "scale"), (d,), "scale")]
     if dm["norm"] == "layernorm":
         out.append(((*prefix, "bias"), (d,), "bias"))
-    return out
-
-
-def layer_leaves(dm: Dict) -> List[Leaf]:
-    """One layer's leaves (unstacked shapes), in the program's layout."""
-    d, hd = dm["d"], dm["hd"]
-    q, kv = dm["H"] * hd, dm["Hkv"] * hd
-    out = _norm_leaves(("attn_norm",), d, dm)
-    out += [(("attn", "wq"), (d, q), "matrix"), (("attn", "wk"), (d, kv), "matrix"),
-            (("attn", "wv"), (d, kv), "matrix"), (("attn", "wo"), (q, d), "matrix")]
-    if dm["bias"]:
-        out += [(("attn", "bq"), (q,), "bias"), (("attn", "bk"), (kv,), "bias"),
-                (("attn", "bv"), (kv,), "bias")]
-    out += _norm_leaves(("mlp_norm",), d, dm)
-    f = dm["ff"]
-    if dm["gated"]:
-        out += [(("mlp", "w_gate"), (d, f), "matrix"), (("mlp", "w_up"), (d, f), "matrix"),
-                (("mlp", "w_down"), (f, d), "matrix")]
-    else:
-        out += [(("mlp", "w_up"), (d, f), "matrix"), (("mlp", "b_up"), (f,), "bias"),
-                (("mlp", "w_down"), (f, d), "matrix"), (("mlp", "b_down"), (d,), "bias")]
     return out
 
 
@@ -83,20 +70,23 @@ def _set(tree: Dict, path: Tuple[str, ...], value) -> None:
     tree[path[-1]] = value
 
 
-def make(dm: Dict, *, program_head: bool):
-    """``make(seed_lo, seed_hi) -> params``, jitted: every weight, bf16.
+def make(dm: Dict, *, program_head: bool, shardings=None):
+    """``make(seed_lo, seed_hi) -> params``, jitted: every weight, bf16 (or
+    the dtype its leaf names).
 
     ``program_head``: the program holds a separate ``(d, vocab_pad)`` head
     leaf (untied layout); for a tied configuration it is filled with the
-    transposed embedding."""
-    leaves = layer_leaves(dm)
+    transposed embedding.  ``shardings``: the params' placement over several
+    chips, as the jitted call's ``out_shardings``; each chip then draws its
+    share of a leaf, and the values are those of one chip."""
+    leaves = blocks.load(dm["block"]).layer_leaves(dm)
 
     def one_layer(key, layer):
         lk = jax.random.fold_in(key, 1 + layer)
         out: Dict = {}
-        for path, shape, kind in leaves:
+        for path, shape, kind, *dtype in leaves:
             _set(out, path, _draw(lk, ("layers", *path), shape, kind)
-                 .astype(jnp.bfloat16))
+                 .astype(dtype[0] if dtype else jnp.bfloat16))
         return out
 
     def build(seed_lo, seed_hi):
@@ -112,11 +102,36 @@ def make(dm: Dict, *, program_head: bool):
                     else _draw(key, ("embedding", "head"),
                                (dm["d"], dm["vocab_pad"]), "matrix"))
             params["embedding"]["head"] = head.astype(jnp.bfloat16)
-        for path, shape, kind in _norm_leaves(("final_norm",), dm["d"], dm):
+        for path, shape, kind in norm_leaves(("final_norm",), dm["d"], dm):
             _set(params, path, _draw(key, path, shape, kind).astype(jnp.bfloat16))
         return params
 
-    return jax.jit(build)
+    return jax.jit(build, out_shardings=shardings)
+
+
+def spread(dm: Dict, devices, *, program_head: bool):
+    """The harness's own placement of the weights over ``devices``, for the
+    reference: a 1-D mesh of the chips, each leaf split on its largest axis
+    that the chip count divides (the layer axis of the stacked layers
+    excepted, which the reference's scan walks), replicated where none
+    does.  ``None`` on one chip."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    n = len(devices)
+    if n == 1:
+        return None
+    mesh = Mesh(list(devices), ("chips",))
+
+    def place(path, leaf):
+        first = 1 if getattr(path[0], "key", None) == "layers" else 0
+        axes = [i for i in range(first, len(leaf.shape)) if leaf.shape[i] % n == 0]
+        spec = [None] * len(leaf.shape)
+        if axes:
+            spec[max(axes, key=lambda i: (leaf.shape[i], i))] = "chips"
+        return NamedSharding(mesh, PartitionSpec(*spec))
+
+    shapes = jax.eval_shape(make(dm, program_head=program_head), *split_seed(0))
+    return jax.tree_util.tree_map_with_path(place, shapes)
 
 
 def split_seed(seed: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
